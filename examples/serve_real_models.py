@@ -2,9 +2,13 @@
 
 The paper's kind is a serving system, so the end-to-end example deploys
 actual jitted models (reduced variants of two assigned architectures) on
-this host with the real thread-pool executor:
+the default JAX device with the real thread-pool executor. Each stage is
+a :class:`repro.serving.runtime.StageRuntime` that greedily generates
+``GEN_TOKENS`` per request; the second stage continues from the first
+stage's tokens.
 
-  1. measured-profile both models with the Profiler's wall-clock backend,
+  1. measured-profile both models under the hardware-menu entry of the
+     device they run on,
   2. plan the two-stage cascade with the Planner against the profile,
   3. deploy the planned config to PipelineExecutor (real centralized
      policy-aware batched queues + replica threads),
@@ -17,108 +21,79 @@ this host with the real thread-pool executor:
 Run:  PYTHONPATH=src python examples/serve_real_models.py
 """
 
+import dataclasses
+
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_smoke
 from repro.core.estimator import Estimator
+from repro.core.hardware import hardware_for_device
 from repro.core.pipeline import linear_pipeline
 from repro.core.planner import Planner
 from repro.core.profiler import ProfileStore, profile_model_measured
 from repro.core.tuner import ClosedLoopTuner, TunerPlanInfo
-from repro.models import build_model
 from repro.serving.executor import PipelineExecutor
 from repro.serving.loop import LiveControlLoop
+from repro.serving.runtime import (
+    GEN_TOKENS, StageRuntime, enable_compile_cache)
 from repro.workload.generator import gamma_trace
 
-SEQ = 32
-SLO = 0.25          # 250 ms end-to-end on this CPU host
+SEQ = 32            # prompt tokens into the first stage
+MAX_BATCH = 16
+SLO = 0.25          # 250 ms end-to-end on a CPU host
 LAMBDA = 30.0       # queries/s
 
 
-def make_stage(arch_id: str):
-    """Build a reduced model + a jitted batch scoring function."""
-    cfg = get_smoke(arch_id)
-    model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-
-    @jax.jit
-    def score(tokens):
-        logits, _ = model.forward(params, {"tokens": tokens})
-        nxt = jnp.argmax(logits[:, -1], axis=-1)
-        # cascade payload: shift the window and append the prediction so
-        # the downstream stage receives the same (SEQ,) token shape
-        return jnp.concatenate([tokens[:, 1:], nxt[:, None]], axis=1)
-
-    def run_batch(payloads):
-        # pad to the next power-of-two bucket: variable batch sizes
-        # would trigger a fresh XLA compile per size (seconds each) and
-        # collapse the pipeline — bucketing is standard serving practice
-        n = len(payloads)
-        bucket = 1
-        while bucket < n:
-            bucket *= 2
-        tokens = jnp.stack([jnp.asarray(p, jnp.int32) for p in payloads]
-                           + [jnp.zeros((SEQ,), jnp.int32)] * (bucket - n))
-        out = jax.block_until_ready(score(tokens))
-        return [np.asarray(o) for o in out[:n]]
-
-    def profile_fn(b):
-        toks = jnp.ones((b, SEQ), jnp.int32)
-        jax.block_until_ready(score(toks))
-
-    def warmup(max_batch: int = 128):
-        bkt = 1
-        while bkt <= max_batch:
-            profile_fn(bkt)
-            bkt *= 2
-
-    return cfg, run_batch, profile_fn, warmup
-
-
 def main() -> None:
-    print("building models (xlstm-125m-smoke -> llama3.2-1b-smoke cascade)")
-    cfg_a, run_a, prof_a, warm_a = make_stage("xlstm-125m")
-    cfg_b, run_b, prof_b, warm_b = make_stage("llama3.2-1b")
+    enable_compile_cache()
+    hw = hardware_for_device(jax.devices()[0])
+    print(f"building + warming models on {hw} "
+          f"(xlstm-125m-smoke -> llama3.2-1b-smoke cascade)")
+    runtimes = {
+        "stage_a": StageRuntime(get_smoke("xlstm-125m"), jax.devices()[:1],
+                                seq_len=SEQ, max_batch=MAX_BATCH),
+        "stage_b": StageRuntime(get_smoke("llama3.2-1b"), jax.devices()[:1],
+                                seq_len=GEN_TOKENS, max_batch=MAX_BATCH),
+    }
 
     print("profiling (measured wall-clock backend) ...")
     store = ProfileStore()
-    store.add(profile_model_measured("stage_a", prof_a,
-                                     batch_sizes=(1, 2, 4, 8, 16)))
-    store.add(profile_model_measured("stage_b", prof_b,
-                                     batch_sizes=(1, 2, 4, 8, 16)))
-    for mid in ("stage_a", "stage_b"):
+    for mid, rt in runtimes.items():
+        store.add(profile_model_measured(mid, rt.profile_batch, hw,
+                                         batch_sizes=rt.buckets))
         p = store.get(mid)
-        print(f"  {mid}: lat(b=1)={p.batch_latency('cpu-1', 1)*1e3:.1f}ms "
-              f"lat(b=8)={p.batch_latency('cpu-1', 8)*1e3:.1f}ms "
-              f"max_thru={p.max_throughput('cpu-1'):.1f} qps")
+        print(f"  {mid}: lat(b=1)={p.batch_latency(hw, 1)*1e3:.1f}ms "
+              f"lat(b=8)={p.batch_latency(hw, 8)*1e3:.1f}ms "
+              f"max_thru={p.max_throughput(hw):.1f} qps")
 
     pipe = linear_pipeline("cascade", ["stage_a", "stage_b"],
-                           {"stage_a": ["cpu-1"], "stage_b": ["cpu-1"]})
+                           {"stage_a": [hw], "stage_b": [hw]})
     sample = gamma_trace(LAMBDA, 1.0, 20, seed=0)
     plan = Planner(pipe, store).plan(sample, SLO)
     print("\nplanned configuration:")
     print(plan.describe())
     if not plan.feasible:
         raise SystemExit("infeasible on this host; lower LAMBDA")
-
-    print("\nwarming batch buckets (pow2 up to 128) ...")
-    warm_a()
-    warm_b()
+    # the planner may extrapolate batch sizes past the profiled buckets;
+    # serve at most the largest warmed one
+    config = plan.config.copy()
+    for s_name, cfg in config.stage_configs.items():
+        config.stage_configs[s_name] = dataclasses.replace(
+            cfg, batch_size=min(cfg.batch_size, MAX_BATCH))
 
     print("deploying to the real executor and serving 15 s of traffic...")
-    solo = {s: store.get(pipe.stages[s].model_id).batch_latency("cpu-1", 1)
+    solo = {s: store.get(pipe.stages[s].model_id).batch_latency(hw, 1)
             for s in pipe.stages}
-    ex = PipelineExecutor(pipe, plan.config, {
-        "stage_a": run_a, "stage_b": run_b,
-    }, solo_latency_s=solo)
+    ex = PipelineExecutor(pipe, config, runtimes, solo_latency_s=solo)
     live = gamma_trace(LAMBDA, 1.0, 15, seed=1)
-    payload = lambda i: jnp.ones((SEQ,), jnp.int32) * (i % 50)  # noqa: E731
+    prompts = np.random.default_rng(0).integers(
+        0, 512, (live.size, SEQ), dtype=np.int32)
+    payload = lambda i: prompts[i % len(prompts)]  # noqa: E731
     lat = ex.serve_trace(live, payload, slo_s=SLO)
 
     est = Estimator(pipe, store)
-    predicted = est.simulate(plan.config, live)
+    predicted = est.simulate(config, live)
     print(f"\nserved {lat.size} queries:")
     print(f"  measured  p50={np.percentile(lat, 50)*1e3:7.1f}ms  "
           f"p99={np.percentile(lat, 99)*1e3:7.1f}ms  "
@@ -133,8 +108,8 @@ def main() -> None:
     # step(EpochTelemetry) interface it uses in co-simulation; a 3x
     # traffic spike should scale the fleet up, then drain it back down
     print("\nclosed loop: 3x spike against the live executor ...")
-    service = est.service_time(plan.config)
-    info = TunerPlanInfo.from_plan(pipe, plan.config, store,
+    service = est.service_time(config)
+    info = TunerPlanInfo.from_plan(pipe, config, store,
                                    gamma_trace(LAMBDA, 1.0, 60, seed=2),
                                    service)
     tuner = ClosedLoopTuner(info, max_replicas=4)

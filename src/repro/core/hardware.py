@@ -16,7 +16,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Tuple
 
-# --- TPU v5e chip constants (also used by roofline/analysis.py) ----------
+# --- TPU v5e chip constants (also used by roofline/analysis.py); the
+# device kinds they apply to are mapped in HARDWARE_BY_DEVICE_KIND -------
 PEAK_FLOPS_BF16 = 197e12      # FLOP/s per chip
 HBM_BW = 819e9                # bytes/s per chip
 ICI_BW = 50e9                 # bytes/s per link
@@ -77,6 +78,24 @@ def get_hardware(name: str) -> HardwareType:
         raise KeyError(
             f"unknown hardware {name!r}; menu: {sorted(HARDWARE_BY_NAME)}"
         ) from None
+
+
+# jax ``Device.device_kind`` -> the menu entry one such device provides.
+# A kind missing here has unmeasured constants: an error, never a default.
+HARDWARE_BY_DEVICE_KIND: Dict[str, str] = {
+    "cpu": "cpu-1",
+    "TPU v5 lite": "tpu-v5e-1",     # TPU v5e as JAX reports it
+}
+
+
+def hardware_for_device(device) -> str:
+    """Menu name for one jax device (e.g. ``jax.devices()[0]``)."""
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"unknown device kind {device.device_kind!r}; known: "
+            f"{sorted(HARDWARE_BY_DEVICE_KIND)}") from None
 
 
 def cheaper_hardware(name: str) -> Tuple[str, ...]:

@@ -201,6 +201,13 @@ class Planner:
     def _sims(self) -> int:
         return self._session.stats["full_sims"] if self._session else 0
 
+    @property
+    def session_stats(self) -> Dict[str, int]:
+        """Counters of the latest plan's scoring session (``{}`` before
+        any; ``device_grids`` > 0 when the jax backend's device grid
+        path scored candidates)."""
+        return dict(getattr(self._session, "stats", {}))
+
     def _p99(self, config: PipelineConfig) -> float:
         """Percentile latency on the session's bound trace (the arrivals
         handed to plan(); this is the incremental simulate_delta path)."""

@@ -11,13 +11,18 @@ state trajectory.
 
 Grid: (batch, d_blocks, n_chunks); the chunk axis iterates innermost
 (sequentially on TPU), carrying the running state h in a VMEM scratch
-tile (D_blk, N) — the same persistence pattern the flash kernel uses for
-its softmax state. Block shapes keep D_blk on the sublane dim and N on
-the lane dim; with D_blk=256, N<=64, the working set is < 4 MiB of VMEM.
+tile — the same persistence pattern the flash kernel uses for its
+softmax state. The state is kept transposed, (N, D_blk): D_blk on the
+lane dim so each step is a lane-dense vector op, N on the sublane dim.
+A is passed as (N, D) and B, C as (B, N, S) for the same layout.
 
-The time recurrence runs as an in-kernel fori_loop over the chunk: each
-step is a (D_blk, N) vector op — wide enough to keep the VPU busy — and
-a (D_blk,) store into the output tile.
+The time recurrence runs as an in-kernel fori_loop over the chunk. Each
+step reads its (1, D_blk) rows of dt and x straight from the refs with a
+dynamic sublane slice, picks its (N, 1) columns of B and C with a
+masked lane reduction (TPU Mosaic has no dynamic lane slice), and
+writes its (1, D_blk) row of y back through a ``pl.ds`` store.
+Operands are widened to f32 before the call so every dynamic row access
+is on an unpacked 32-bit tile.
 """
 
 from __future__ import annotations
@@ -32,33 +37,30 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_D_BLOCK = 256
 
 
-def _mamba_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref,
+def _mamba_kernel(dt_ref, x_ref, bt_ref, ct_ref, at_ref, h0_ref,
                   y_ref, hout_ref, h_scratch, *,
                   chunk: int, n_chunks: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
-        h_scratch[...] = h0_ref[0].astype(jnp.float32)
+        h_scratch[...] = h0_ref[0]
 
-    a = a_ref[...].astype(jnp.float32)            # (D_blk, N)
-    dt = dt_ref[0].astype(jnp.float32)            # (chunk, D_blk)
-    x = x_ref[0].astype(jnp.float32)              # (chunk, D_blk)
-    bm = b_ref[0].astype(jnp.float32)             # (chunk, N)
-    cm = c_ref[0].astype(jnp.float32)             # (chunk, N)
+    at = at_ref[...]                              # (N, D_blk)
+    bt = bt_ref[0]                                # (N, chunk)
+    ct = ct_ref[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, bt.shape, 1)
 
     def step(t, h):
-        dt_t = jax.lax.dynamic_slice_in_dim(dt, t, 1, 0)[0]   # (D_blk,)
-        x_t = jax.lax.dynamic_slice_in_dim(x, t, 1, 0)[0]
-        b_t = jax.lax.dynamic_slice_in_dim(bm, t, 1, 0)[0]    # (N,)
-        c_t = jax.lax.dynamic_slice_in_dim(cm, t, 1, 0)[0]
-        a_bar = jnp.exp(dt_t[:, None] * a)                    # (D_blk, N)
-        h = a_bar * h + (dt_t * x_t)[:, None] * b_t[None, :]
-        y_t = jnp.sum(h * c_t[None, :], axis=1)               # (D_blk,)
-        # NB: every ref index must be a slice (pl.ds/:): a raw int index
-        # crashes interpret-mode state discharge (_swap_discharge_rule)
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y_t[None, None, :].astype(y_ref.dtype))
+        dt_t = dt_ref[0, pl.ds(t, 1), :]                      # (1, D_blk)
+        x_t = x_ref[0, pl.ds(t, 1), :]
+        pick = lane == t
+        b_t = jnp.sum(jnp.where(pick, bt, 0.0), axis=1, keepdims=True)
+        c_t = jnp.sum(jnp.where(pick, ct, 0.0), axis=1, keepdims=True)
+        h = jnp.exp(dt_t * at) * h + b_t * (dt_t * x_t)       # (N, D_blk)
+        # analysis: allow JAX01 — y_ref is the kernel's output VMEM ref;
+        # a Pallas ref store runs on every step, it is not host state
+        y_ref[0, pl.ds(t, 1), :] = jnp.sum(h * c_t, axis=0, keepdims=True)
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_scratch[...])
@@ -66,7 +68,7 @@ def _mamba_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref,
 
     @pl.when(ci == n_chunks - 1)
     def _flush():
-        hout_ref[...] = h[None].astype(hout_ref.dtype)
+        hout_ref[0] = h
 
 
 def mamba_scan(
@@ -92,6 +94,7 @@ def mamba_scan(
         db = d
     nd = d // db
 
+    f32 = jnp.float32
     kernel = functools.partial(_mamba_kernel, chunk=chunk,
                                n_chunks=n_chunks)
     y, h_last = pl.pallas_call(
@@ -100,20 +103,22 @@ def mamba_scan(
         in_specs=[
             pl.BlockSpec((1, chunk, db), lambda bb, di, ci: (bb, ci, di)),
             pl.BlockSpec((1, chunk, db), lambda bb, di, ci: (bb, ci, di)),
-            pl.BlockSpec((1, chunk, n), lambda bb, di, ci: (bb, ci, 0)),
-            pl.BlockSpec((1, chunk, n), lambda bb, di, ci: (bb, ci, 0)),
-            pl.BlockSpec((db, n), lambda bb, di, ci: (di, 0)),
-            pl.BlockSpec((1, db, n), lambda bb, di, ci: (bb, di, 0)),
+            pl.BlockSpec((1, n, chunk), lambda bb, di, ci: (bb, 0, ci)),
+            pl.BlockSpec((1, n, chunk), lambda bb, di, ci: (bb, 0, ci)),
+            pl.BlockSpec((n, db), lambda bb, di, ci: (0, di)),
+            pl.BlockSpec((1, n, db), lambda bb, di, ci: (bb, 0, di)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, db), lambda bb, di, ci: (bb, ci, di)),
-            pl.BlockSpec((1, db, n), lambda bb, di, ci: (bb, di, 0)),
+            pl.BlockSpec((1, n, db), lambda bb, di, ci: (bb, 0, di)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, d), x.dtype),
-            jax.ShapeDtypeStruct((bsz, d, n), h0.dtype),
+            jax.ShapeDtypeStruct((bsz, s, d), f32),
+            jax.ShapeDtypeStruct((bsz, n, d), f32),
         ],
-        scratch_shapes=[pltpu.VMEM((db, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, db), f32)],
         interpret=interpret,
-    )(dt, x, b, c, a, h0)
-    return y, h_last
+    )(dt.astype(f32), x.astype(f32),
+      b.astype(f32).swapaxes(1, 2), c.astype(f32).swapaxes(1, 2),
+      a.astype(f32).T, h0.astype(f32).swapaxes(1, 2))
+    return y.astype(x.dtype), h_last.swapaxes(1, 2).astype(h0.dtype)

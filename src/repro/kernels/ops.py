@@ -1,6 +1,8 @@
 """Jit-friendly kernel entry points with backend dispatch.
 
-On TPU the Pallas kernels run natively; elsewhere (this CPU container,
+On TPU the Pallas kernels run natively, and a call the kernels cannot
+serve (a shape that does not tile, a mask no kernel takes) raises rather
+than quietly running the reference on the chip. Elsewhere (CPU hosts,
 and any non-TPU backend) the pure-jnp references execute so models, smoke
 tests, and the dry-run lowering all use the XLA path. Set
 ``REPRO_FORCE_PALLAS_INTERPRET=1`` to route through the Pallas kernels in
@@ -51,26 +53,30 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     """General attention entry point.
 
     `kind` describes the mask structurally so the TPU path can use the
-    flash kernels: "causal" | "full" | "decode". When kind is None (or an
-    explicit irregular mask is supplied) the jnp reference handles it.
+    flash kernels: "causal" | "full" | "decode". Off the TPU, kind None
+    (an explicit irregular mask) and untileable shapes take the jnp
+    reference; on the TPU they raise.
     """
     q = q.astype(compute_dtype)
     k = k.astype(compute_dtype)
     v = v.astype(compute_dtype)
     pallas = _use_pallas()
     interp = _force_interpret()
+    sq, sk = q.shape[1], k.shape[1]
     if (pallas or interp) and kind in ("causal", "full"):
-        sq, sk = q.shape[1], k.shape[1]
         if sq % min(128, sq) == 0 and sk % min(128, sk) == 0:
             return _pallas_flash(q, k, v, causal=(kind == "causal"),
                                  window=window, interpret=interp)
     if (pallas or interp) and kind == "decode" and valid_len is not None:
-        smax = k.shape[1]
-        if smax % min(512, smax) == 0:
+        if sk % min(512, sk) == 0:
             return _pallas_decode(q, k, v, valid_len, window=window,
                                   interpret=interp)
+    if pallas:
+        raise NotImplementedError(
+            f"no Pallas attention kernel for kind={kind!r}, q {q.shape}, "
+            f"k {k.shape} on TPU (flash needs Sq, Sk <= 128 or multiples "
+            f"of 128; decode needs Smax <= 512 or a multiple of 512)")
     scale = 1.0 / math.sqrt(q.shape[-1])
-    sq, sk = q.shape[1], k.shape[1]
     if kind in ("causal", "full") and mask is None:
         # XLA path for structural masks: blockwise flash above the size
         # threshold (keeps live scores O(bq x bk) — see xla_flash.py),

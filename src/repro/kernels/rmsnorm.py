@@ -3,7 +3,11 @@
 Row-tiled: grid over blocks of rows, each block normalizing (BR, D) in
 VMEM with an fp32 mean-of-squares reduction fused with the scale multiply,
 avoiding the separate variance/normalize/scale HLO round-trips through HBM.
-D is the lane dimension; BR rows per block keeps the tile MXU/VPU aligned.
+D is the lane dimension. A row count up to ``block_rows`` is one block of
+the whole array; above it BR = ``block_rows`` (a multiple of the 8-row
+sublane tile) and the grid takes ceil(rows / BR) steps. A ragged last
+block reads unspecified rows past the end and its out-of-range writes are
+dropped, which is harmless because every row normalizes on its own.
 """
 
 from __future__ import annotations
@@ -34,11 +38,11 @@ def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6,
     rows = 1
     for dim in orig_shape[:-1]:
         rows *= dim
+    if block_rows % 8:
+        raise ValueError(f"block_rows={block_rows} is not a multiple of 8")
     x2 = x.reshape(rows, d)
     br = min(block_rows, rows)
-    if rows % br:
-        br = 1  # ragged fallback: one row at a time
-    n = rows // br
+    n = pl.cdiv(rows, br)
 
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),

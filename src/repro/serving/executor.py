@@ -1,5 +1,5 @@
 """Real (wall-clock) pipeline executor: policy-aware centralized batched
-queues + thread-pool model replicas serving actual JAX models on CPU.
+queues + thread-pool model replicas serving actual JAX models.
 
 This is the runtime half of the serving system: the same
 Pipeline/PipelineConfig the Planner emits is deployed over real queues
@@ -203,7 +203,11 @@ class PipelineExecutor:
         shared-memory ring — same LiveQueue/batch-formation contract,
         but service escapes the GIL and injected crashes SIGKILL real
         processes. Stage fns must be fork-safe for the process backend
-        (or importable, with ``start_method="spawn"``).
+        (or importable, with ``start_method="spawn"``). The process
+        backend is for CPU-only stages: a TPU belongs to one process,
+        and a worker forked from a parent that holds it cannot reach it.
+        Device stages use ``"thread"``, one replica per thread (see
+        :class:`repro.serving.runtime.StageRuntime`).
       slab_bytes: per-replica shared-memory slab size for the process
         backend; split into ``ring_depth`` buffers (oversize batches
         fall back to chunked-slab transport).

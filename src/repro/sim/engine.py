@@ -343,8 +343,9 @@ class TraceSession:
         self._accum_cache: "collections.OrderedDict[Tuple, Tuple]" = \
             collections.OrderedDict()
         self._accum_bytes = 0
+        # device_grids counts candidate grids scored on the device path
         self.stats = {"full_sims": 0, "stage_sims": 0, "stage_hits": 0,
-                      "accum_hits": 0}
+                      "accum_hits": 0, "device_grids": 0}
 
     # -- cache keys ---------------------------------------------------------
     def _stage_key(self, stage: str, config: PipelineConfig,
@@ -651,7 +652,9 @@ class TraceSession:
         .grid_stage_percentiles`): the fixed stages simulate once on
         host, the varied stage's (lut, batch, replicas, timeout) grid
         fills and reduces to percentiles on device. Bit-identical to the
-        host loop (property-tested); ineligible sets fall through to it.
+        host loop on the CPU (property-tested; a TPU's emulated f64 is
+        not, see :mod:`repro.sim.jax_backend`); ineligible sets fall
+        through to it.
         """
         configs = list(configs)
         if self.backend == "jax" and not replica_schedules:
@@ -664,7 +667,7 @@ class TraceSession:
                               p: float) -> Optional[List[float]]:
         """Device-grid scoring of an eligible candidate set, or None.
 
-        Eligible: jax importable; enough uncached distinct candidates
+        Eligible: enough uncached distinct candidates
         and a long enough trace to beat per-shape compile + dispatch;
         the candidates differ in exactly one stage; that stage is a sink
         (no descendants), so every other stage's entry is candidate-
@@ -675,8 +678,6 @@ class TraceSession:
         """
         from repro.sim import jax_backend
 
-        if not jax_backend.available():
-            return None
         uncached: Dict[Tuple, PipelineConfig] = {}
         for c in configs:
             ck = self.config_key(c)
@@ -755,6 +756,7 @@ class TraceSession:
             engine.rpc_delay_s, luts, effs, reps, touts, p)
         self.stats["full_sims"] += len(cands)
         self.stats["stage_sims"] += len(cands)
+        self.stats["device_grids"] += 1
         for ck, v in zip(uncached, vals):
             self._pctl_cache[(self.backend, ck, p)] = float(v)
         while len(self._pctl_cache) > self._max_pctl_entries:

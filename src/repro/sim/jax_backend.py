@@ -29,9 +29,12 @@ property suite in ``tests/test_jax_backend.py``):
   is ``"jax"``.
 
 Float64 discipline: the repo's model/kernel stack runs jax in its f32
-default; this module scopes ``jax.experimental.enable_x64`` around every
+default; this module scopes ``jax.enable_x64(True)`` around every
 trace and call instead of flipping the global flag, so simulator math is
-IEEE-double (matching numpy) without disturbing the model zoo.
+IEEE-double (matching numpy) without disturbing the model zoo. That holds
+on the CPU. A TPU emulates f64 without IEEE rounding: on a TPU v5e the
+grid percentiles differed from numpy by up to ~5e-12 s while the plans
+stayed identical (PERF.md), so bit identity is a CPU-only contract.
 
 Auto-selection: single fills fall back to numpy below
 ``REPRO_JAX_FILL_THRESHOLD`` queries. ``benchmarks/bench_planner_scale.py
@@ -53,16 +56,9 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-try:  # jax is an install-time dependency, but stay importable without it
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-    from jax.experimental import enable_x64
-
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover - exercised only on jax-less hosts
-    jax = None
-    _HAVE_JAX = False
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 _FAR_FUTURE = 1e18
 
@@ -84,11 +80,6 @@ _GRID_CHUNK = int(os.environ.get("REPRO_JAX_GRID_CHUNK", 256))
 # ~eff_batch queries per step, so backlogged chunks retire after k/b
 # steps instead of burning the worst-case k (see grid_stage_percentiles)
 _GRID_SEGMENTS = int(os.environ.get("REPRO_JAX_GRID_SEGMENTS", 8))
-
-
-def available() -> bool:
-    """True when jax is importable (the backend can be selected)."""
-    return _HAVE_JAX
 
 
 def _pow2_at_least(x: int) -> int:
@@ -208,7 +199,7 @@ def fill_static(ready: np.ndarray, lut: np.ndarray, eff_batch: int,
     ready_pad = np.concatenate([ready, np.full(Bmax, np.inf)])
     lut_pad = np.zeros(Bmax + 1)
     lut_pad[:eff_batch + 1] = lut[:eff_batch + 1]
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _static_fill_fn(k, k, Bmax, Rcap, bool(timeout_s > 0.0))
         _, _, ends, counts = fn(
             jnp.asarray(ready_pad), jnp.asarray(lut_pad), eff_batch,
@@ -358,7 +349,7 @@ def fill_dynamic(ready: np.ndarray, lut: np.ndarray, eff_batch: int,
     ready_pad = np.concatenate([ready, np.full(Bmax, np.inf)])
     lut_pad = np.zeros(Bmax + 1)
     lut_pad[:eff_batch + 1] = lut[:eff_batch + 1]
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _dynamic_fill_fn(k, Bmax, Rcap, M, max(Mr, 1), T)
         done, ends, counts, is_batch = fn(
             jnp.asarray(ready_pad), jnp.asarray(lut_pad), eff_batch,
@@ -385,11 +376,9 @@ def fifo_fill(ready: np.ndarray, latency_lut: np.ndarray, eff_batch: int,
               timeout_s: float
               ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
     """Device FIFO fill, or None when the numpy kernel should run
-    instead (jax missing, fill below the crossover threshold, or a
-    negative profiled latency — the sorted-buffer insert assumes
+    instead (fill below the crossover threshold, or a negative
+    profiled latency — the sorted-buffer insert assumes
     completions never precede starts, like the numpy blocked kernel)."""
-    if not _HAVE_JAX:
-        return None
     k = int(ready.shape[0])
     if k < _JAX_FILL_THRESHOLD or k == 0:
         return None
@@ -447,7 +436,7 @@ def percentile_1d(values: np.ndarray, p: float) -> float:
     if n == 0:
         return 0.0
     prev, nxt, gamma = _quantile_params(n, p)
-    with enable_x64():
+    with jax.enable_x64(True):
         s = jnp.sort(jnp.asarray(values))
         a, b = float(s[prev]), float(s[nxt])
     return float(_host_lerp(np.float64(a), np.float64(b), gamma))
@@ -532,7 +521,7 @@ def grid_stage_percentiles(
     ], kind="stable")
     out = np.empty(C)
     kth = (prev, nxt) if nxt > prev else (prev,)
-    with enable_x64():
+    with jax.enable_x64(True):
         ready_j = jnp.asarray(ready_pad)
         for s in range(0, C, chunk):
             lanes = perm[s:s + chunk]

@@ -26,10 +26,6 @@ from repro.core.planner import BeamPlanner, Planner
 from repro.sim import SimEngine, simulate_stage
 from repro.sim import jax_backend as jb
 
-pytestmark = pytest.mark.skipif(
-    not jb.available(), reason="jax not installed")
-
-
 # -- helpers ----------------------------------------------------------------
 
 def _both_fills(ready, lut, max_batch, replicas,

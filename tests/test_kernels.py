@@ -149,10 +149,10 @@ def test_rmsnorm_sweep(shape, dtype):
 
 
 def test_rmsnorm_ragged_rows():
-    """Row counts not divisible by the block fall back to row-at-a-time."""
-    x = jax.random.normal(jax.random.PRNGKey(9), (7, 320))
+    """Row counts not divisible by the block run a ragged last block."""
+    x = jax.random.normal(jax.random.PRNGKey(9), (19, 320))
     g = jnp.ones((320,))
-    out = rmsnorm(x, g, block_rows=4, interpret=True)
+    out = rmsnorm(x, g, block_rows=8, interpret=True)
     np.testing.assert_allclose(out, ref.rmsnorm_ref(x, g), atol=2e-5,
                                rtol=2e-5)
 
@@ -173,6 +173,21 @@ def test_ops_force_interpret(monkeypatch):
     got = ops.attention(q, q, q, None, jnp.float32, kind="causal")
     exp = ref.flash_attention_ref(q, q, q, causal=True)
     np.testing.assert_allclose(got, exp, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("kind,sk", [("causal", 200), ("decode", 600),
+                                     (None, 128)])
+def test_ops_attention_raises_on_tpu_without_a_kernel(monkeypatch, kind,
+                                                      sk):
+    """On a TPU, a call no kernel serves raises instead of quietly taking
+    the reference (here: untiled flash and decode lengths, no kind)."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    sq = 1 if kind == "decode" else sk
+    q = jnp.zeros((1, sq, 4, 64))
+    k = jnp.zeros((1, sk, 2, 64))
+    with pytest.raises(NotImplementedError, match="no Pallas attention"):
+        ops.attention(q, k, k, None, jnp.float32, kind=kind, valid_len=3)
 
 
 # ------------------------------------------------------- xla_flash (+ VJP)

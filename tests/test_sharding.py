@@ -6,9 +6,11 @@ subprocess for the 8-device case so the main pytest process keeps a
 single CPU device (smoke tests must see 1 device).
 """
 
+import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -90,7 +92,9 @@ _SUBPROC = textwrap.dedent("""
     from repro.train.trainer import make_train_step
 
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = get_smoke("granite-moe-1b-a400m")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -122,8 +126,7 @@ def test_multi_device_train_step_subprocess():
     """8 placeholder devices, (2,4) mesh, real sharded train step."""
     r = subprocess.run([sys.executable, "-c", _SUBPROC],
                        capture_output=True, text=True, timeout=600,
-                       env={**__import__("os").environ,
-                            "PYTHONPATH": "src"},
-                       cwd="/root/repo")
+                       env={**os.environ, "PYTHONPATH": "src"},
+                       cwd=Path(__file__).resolve().parents[1])
     assert r.returncode == 0, r.stderr[-3000:]
     assert "OK" in r.stdout
