@@ -164,7 +164,8 @@ def run() -> dict:
     lat = ex.serve_trace(trace, payload, timeout_s=30.0, slo_s=SLO)
     wall = time.perf_counter() - t0
     real_att = float((lat <= SLO).mean())
-    real_batch = ex.batch_stats()
+    real_batch = {s: float(b.mean()) if b.size else 0.0
+                  for s, b in ex.batch_sizes().items()}
     real_p50 = float(np.percentile(lat[np.isfinite(lat)], 50.0))
     ex.shutdown()
 
@@ -266,7 +267,8 @@ def run() -> dict:
             "peak_replicas_total": peak_total,
             "final_replicas_total": final_total,
             "mean_cost_per_hr": live.mean_cost_per_hr(),
-            "mean_batch": live.batch_stats(),
+            "mean_batch": {s: float(b.mean()) if b.size else 0.0
+                           for s, b in live.batch_sizes.items()},
         },
         "cosim": {
             "miss_rate": co.miss_rate,

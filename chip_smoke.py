@@ -220,8 +220,8 @@ def phase_serve(dev, hw: str) -> None:
         ex.shutdown()
     print(f"[c serve] {N_REQUESTS} requests: p50={np.median(lat) * 1e3:.1f}"
           f" ms max={lat.max() * 1e3:.1f} ms, mean batch "
-          f"{ex.batch_stats()[stage]:.2f}, compiles in the serving window: "
-          f"{compiles.n}")
+          f"{ex.batch_sizes()[stage].mean():.2f}, compiles in the serving "
+          f"window: {compiles.n}")
     check(bool(np.isfinite(lat).all()), "a request was not answered")
     for i in range(N_REQUESTS):
         out = answers.get(i)

@@ -100,8 +100,9 @@ def main() -> None:
           f"miss={float((lat > SLO).mean()):.4f}")
     print(f"  estimator p50={predicted.percentile(50)*1e3:7.1f}ms  "
           f"p99={predicted.p99*1e3:7.1f}ms (Fig. 8 fidelity check)")
-    print(f"  mean batch sizes: "
-          f"{ {k: round(v, 1) for k, v in ex.batch_stats().items()} }")
+    mean_batch = {k: round(float(b.mean()), 1) if b.size else 0.0
+                  for k, b in ex.batch_sizes().items()}
+    print(f"  mean batch sizes: {mean_batch}")
 
     # ---- close the loop on the running pipeline -------------------------
     # the ClosedLoopTuner drives REAL threads through the same

@@ -120,6 +120,7 @@ def decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, group, dv), q.dtype),
+        name="decode_attention",
         interpret=interpret,
     )(vl, qg, kt, vt)
     return out.reshape(b, 1, h, dv)

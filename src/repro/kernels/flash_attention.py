@@ -122,6 +122,7 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, 1, bq, dv),
                                lambda bb, hh, qi, ki: (bb, hh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
+        name="flash_attention",
         scratch_shapes=[
             pltpu.VMEM((bq, dv), jnp.float32),   # acc
             pltpu.VMEM((bq, 1), jnp.float32),    # running max

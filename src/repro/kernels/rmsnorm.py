@@ -53,6 +53,7 @@ def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
+        name="rmsnorm",
         interpret=interpret,
     )(x2, scale)
     return out.reshape(orig_shape)

@@ -79,6 +79,7 @@ from repro.serving.procpool import (
     ReplicaDead,
     StageWorkerError,
 )
+from repro.serving.spans import span
 
 
 @dataclasses.dataclass
@@ -265,8 +266,9 @@ class PipelineExecutor:
         # (stage, exception) per uncaught worker crash — failing loudly
         # beats a silent replica loss that deadlocks the run
         self.worker_failures: List[Tuple[str, BaseException]] = []  # guarded-by: _lock
-        # injection-lag telemetry of the most recent trace injection
-        self._injection_stats: Optional[Dict[str, float]] = None  # guarded-by: _lock
+        # how late each request of the run was injected past its
+        # nominal arrival, in injection order (seconds)
+        self._lags: List[float] = []  # guarded-by: _lock
         _install_worker_excepthook()
         # fault injection + recovery (repro.faults)
         self._faults = faults
@@ -340,7 +342,7 @@ class PipelineExecutor:
         with self._lock:
             self._t0 = time.perf_counter()
             self.worker_failures = []
-            self._injection_stats = None
+            self._lags = []
         for st in self._stages.values():
             with st.cond:
                 st.arrived = st.completed = st.dropped = 0
@@ -711,26 +713,38 @@ class PipelineExecutor:
 
     def _dispatch_loop(self, st: _Stage, t_active: float) -> None:
         """Thread-backend dispatcher: form, serve inline, complete —
-        strictly synchronous, one batch at a time."""
+        strictly synchronous, one batch at a time. In a profiler trace
+        each batch is an ``executor.form`` span (waiting for work
+        included) and an ``executor.batch`` span around the stage call
+        and ``executor.complete`` (:mod:`repro.serving.spans`)."""
         while True:
-            verdict, batch, shed, _ = self._formation_step(
-                st, t_active, None, block=True)
-            if verdict == "exit":
-                return
-            batch = self._prep_batch(st, batch, shed)
+            with span("executor.form") as form:
+                verdict, batch, shed, _ = self._formation_step(
+                    st, t_active, None, block=True)
+                if verdict == "exit":
+                    return
+                batch = self._prep_batch(st, batch, shed)
+                if form is not None and batch:
+                    form.set_metadata(rows=len(batch), wait_ms=1e3 * (
+                        self.now() - min(r.t_arrival for r in batch)))
             if not batch:
                 continue
             t_start = self.now()
-            err: Optional[BaseException] = None
-            outs: List[Any] = []
-            try:
-                outs = st.fn([r.payload for r in batch])
-            except Exception as e:  # noqa: BLE001 — a dead worker
-                # deadlocks the pipeline; surface the failure per-request
-                err = e
-                outs = [None] * len(batch)
-            if self._complete_batch(st, batch, t_start, outs, err, False):
-                return
+            with span("executor.batch", stage=st.name, rows=len(batch),
+                      rid0=batch[0].rid, t=t_start):
+                err: Optional[BaseException] = None
+                outs: List[Any] = []
+                try:
+                    outs = st.fn([r.payload for r in batch])
+                except Exception as e:  # noqa: BLE001 — a dead worker
+                    # deadlocks the pipeline; surface the failure
+                    # per-request
+                    err = e
+                    outs = [None] * len(batch)
+                with span("executor.complete"):
+                    if self._complete_batch(st, batch, t_start, outs, err,
+                                            False):
+                        return
 
     def _abort_inflight(self, st: _Stage,
                         inflight: "deque") -> None:
@@ -957,24 +971,31 @@ class PipelineExecutor:
             cb(req)
 
     def inject(self, req: _Request) -> None:
+        """Admit one request; its lag past its nominal arrival is
+        recorded (:meth:`injection_stats`) and is the ``lag_us`` of its
+        ``executor.inject`` span."""
+        lag = self.now() - req.t_arrival
         # the injection guard keeps `pending` positive while entry
         # messages land, so a fast first branch finishing cannot
         # finalize the request before its remaining entry edges route
         with self._lock:
+            self._lags.append(lag)
             req.pending += 1
-        ready = req.t_arrival + self.hop_delay_s
-        for e in self.pipeline.entry_edges():
-            self._route_child(e.dst, req, self._coin(e.probability), ready)
-        with self._lock:
-            req.pending -= 1
-            finished = req.pending == 0
-            routed = bool(req.visited)
-        if finished:
-            if routed:
-                self._finalize(req)
-            else:       # nothing fired anywhere: never entered a queue
-                req.t_done = req.t_arrival
-                req.done.set()
+        with span("executor.inject", rid=req.rid, lag_us=1e6 * lag):
+            ready = req.t_arrival + self.hop_delay_s
+            for e in self.pipeline.entry_edges():
+                self._route_child(e.dst, req, self._coin(e.probability),
+                                  ready)
+            with self._lock:
+                req.pending -= 1
+                finished = req.pending == 0
+                routed = bool(req.visited)
+            if finished:
+                if routed:
+                    self._finalize(req)
+                else:       # nothing fired anywhere: never entered a queue
+                    req.t_done = req.t_arrival
+                    req.done.set()
 
     def release(self, reqs: List[_Request]) -> int:
         """Cancel every unfinished request in `reqs`: queued occurrences
@@ -1047,28 +1068,23 @@ class PipelineExecutor:
                 f"{context} ({stages}) — results would silently "
                 f"under-serve")
 
-    def _note_injection_lags(self, lags: np.ndarray) -> None:
-        """Record injection-lag telemetry for the run (how late each
-        request was admitted past its nominal absolute deadline)."""
-        lags = np.asarray(lags, dtype=np.float64)
-        stats = {
-            "n": int(lags.size),
-            "max_lag_s": float(lags.max()) if lags.size else 0.0,
-            "p99_lag_s": (float(np.percentile(lags, 99.0))
-                          if lags.size else 0.0),
-            "mean_lag_s": float(lags.mean()) if lags.size else 0.0,
-        }
+    def injection_lags(self) -> np.ndarray:
+        """How late each request of the run was injected past its
+        nominal arrival (seconds), in injection order."""
         with self._lock:
-            self._injection_stats = stats
+            return np.asarray(self._lags, dtype=np.float64)
 
     def injection_stats(self) -> Optional[Dict[str, float]]:
-        """Injection-lag telemetry of the most recent trace injection
-        (``serve_trace`` or :class:`~repro.serving.ingress.AsyncIngress`):
+        """Injection-lag telemetry of the run (every :meth:`inject`
+        since :meth:`start_run`, whichever injector made it):
         ``{n, max_lag_s, p99_lag_s, mean_lag_s}``, or None before the
         first injection of a run."""
-        with self._lock:
-            return (dict(self._injection_stats)
-                    if self._injection_stats is not None else None)
+        lags = self.injection_lags()
+        if not lags.size:
+            return None
+        return {"n": int(lags.size), "max_lag_s": float(lags.max()),
+                "p99_lag_s": float(np.percentile(lags, 99.0)),
+                "mean_lag_s": float(lags.mean())}
 
     def serve_trace(self, arrivals: np.ndarray, payload_fn,
                     time_scale: float = 1.0,
@@ -1108,7 +1124,6 @@ class PipelineExecutor:
                     else None)
         self.start_run()
         reqs: List[_Request] = []
-        lags = np.zeros(n, dtype=np.float64)
         for i in range(n):
             t_arr = float(arrivals[i])
             while True:
@@ -1123,8 +1138,6 @@ class PipelineExecutor:
                            deadline)
             reqs.append(req)
             self.inject(req)
-            lags[i] = self.now() - t_arr
-        self._note_injection_lags(lags)
         self.await_all(reqs, timeout_s)
         self.release(reqs)
         self.check_worker_failures()
@@ -1157,14 +1170,6 @@ class PipelineExecutor:
             with st.cond:
                 sizes = [b for _, b in st.batch_log]
             out[s] = np.asarray(sizes, dtype=np.int64)
-        return out
-
-    def batch_stats(self) -> Dict[str, float]:
-        out: Dict[str, float] = {}
-        for s, st in self._stages.items():
-            with st.cond:
-                sizes = [b for _, b in st.batch_log]
-            out[s] = float(np.mean(sizes)) if sizes else 0.0
         return out
 
     def dataplane_stats(self) -> Dict[str, DataplaneStats]:

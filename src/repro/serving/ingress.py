@@ -9,8 +9,9 @@ owning a round-robin substream of the trace and sleeping toward the
 a late injection catches up on the next arrival instead of compounding
 drift). Requests are stamped with their nominal arrival, so measured
 latency and deadlines are charged against the intended schedule, and
-per-request injection lag is recorded (:class:`IngressStats`, also
-mirrored into :meth:`PipelineExecutor.injection_stats`).
+per-request injection lag is recorded by the executor
+(:meth:`PipelineExecutor.injection_stats`) and reported here too
+(:class:`IngressStats`).
 
 The executor's worker threads (or worker processes, with
 ``backend="process"``) are untouched: coroutines only sleep, build
@@ -73,7 +74,7 @@ class PayloadRing:
 class IngressStats:
     """Injection fidelity of one open-loop trace replay."""
 
-    lag_s: np.ndarray           # per-request injection lag (seconds)
+    lag_s: np.ndarray           # seconds, per request in injection order
     injected: int
     clients: int
 
@@ -137,11 +138,9 @@ class AsyncIngress:
         deadlines = (arrivals + slo_s * time_scale if slo_s is not None
                      else np.full(n, np.inf))
         reqs: List[Optional[_Request]] = [None] * n
-        lags = np.zeros(n, dtype=np.float64)
         ex.start_run()
-        asyncio.run(self._drive(arrivals, payloads, deadlines, reqs, lags))
-        ex._note_injection_lags(lags)
-        stats = IngressStats(lag_s=lags, injected=n,
+        asyncio.run(self._drive(arrivals, payloads, deadlines, reqs))
+        stats = IngressStats(lag_s=ex.injection_lags(), injected=n,
                              clients=min(self.clients, max(n, 1)))
         live = [r for r in reqs if r is not None]
         ex.await_all(live, timeout_s)
@@ -156,8 +155,7 @@ class AsyncIngress:
 
     async def _drive(self, arrivals: np.ndarray, payloads: Any,
                      deadlines: np.ndarray,
-                     reqs: List[Optional[_Request]],
-                     lags: np.ndarray) -> None:
+                     reqs: List[Optional[_Request]]) -> None:
         ex = self.executor
         n = int(arrivals.size)
         if n == 0:
@@ -184,6 +182,5 @@ class AsyncIngress:
                                float(deadlines[i]))
                 reqs[i] = req
                 ex.inject(req)
-                lags[i] = ex.now() - arrivals[i]
 
         await asyncio.gather(*(client(c) for c in range(k)))

@@ -77,10 +77,6 @@ class LiveLoopResult(CostAccounting):
     def _cost_t_end_default(self) -> float:
         return float(self.arrival.max()) if self.arrival.size else 0.0
 
-    def batch_stats(self) -> Dict[str, float]:
-        return {s: (float(b.mean()) if b.size else 0.0)
-                for s, b in self.batch_sizes.items()}
-
 
 class LiveControlLoop:
     """Wall-clock epoch stepping of one executor + one controller.
@@ -120,7 +116,6 @@ class LiveControlLoop:
         # payloads are pre-built so payload_fn cost never eats into the
         # inter-arrival gaps at high rate
         payloads = [payload_fn(i) for i in range(n)]
-        lags: List[float] = []
         for i in range(n):
             t_arr = float(arrivals[i])
             # absolute-deadline wait on the stop event: a stop (run cut
@@ -133,7 +128,6 @@ class LiveControlLoop:
                 if dt <= 0.0:
                     break
                 if stop.wait(dt):
-                    ex._note_injection_lags(np.asarray(lags))
                     return
             if stop.is_set():
                 break
@@ -143,8 +137,6 @@ class LiveControlLoop:
             req = _Request(i, t_arr, payloads[i], t_arr + self.slo)
             reqs.append(req)
             ex.inject(req)
-            lags.append(ex.now() - t_arr)
-        ex._note_injection_lags(np.asarray(lags))
 
     # -- one epoch's telemetry --------------------------------------------
     def _telemetry(self, epoch: int, t0: float, t1: float,

@@ -14,6 +14,10 @@ cache, all in one jitted program per batch bucket.
   the prompt length and the cache length are checked or rounded against
   the Pallas kernels' tiling (flash: Sq <= 128 or a multiple of 128;
   decode: Smax <= 512 or a multiple of 512).
+* The host's steps of a call (``runtime.pad``, ``runtime.put``,
+  ``runtime.launch``, ``runtime.fetch``) and the program's phases
+  (``prefill``, ``decode``) are named in a profiler trace
+  (:mod:`repro.serving.spans`).
 * Each executor replica is a worker thread. The first batch a thread
   serves binds it to the next device, round robin, so ``replicas=4``
   over four devices puts one replica on each; ``batches`` counts the
@@ -34,6 +38,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.models import build_model
 from repro.models.config import ArchConfig
+from repro.serving.spans import span
 
 REPO_ROOT = Path(__file__).resolve().parents[3]
 GEN_TOKENS = 8      # greedy tokens generated per request
@@ -119,8 +124,11 @@ class StageRuntime:
         token i is the argmax of logits i; logits 0 come from the prefill,
         the rest from one cached decode step each."""
         model = self.model
-        logits, cache = model.prefill(params, {"tokens": tokens}, self.smax)
-        first = logits[:, -1]
+        with jax.named_scope("prefill"):
+            logits, cache = model.prefill(params, {"tokens": tokens},
+                                          self.smax)
+            first = logits[:, -1]
+            init = (jnp.argmax(first, axis=-1).astype(jnp.int32), cache)
 
         def step(carry, pos):
             tok, cache = carry
@@ -128,12 +136,12 @@ class StageRuntime:
             lg = lg[:, -1]
             return (jnp.argmax(lg, axis=-1).astype(jnp.int32), cache), lg
 
-        init = (jnp.argmax(first, axis=-1).astype(jnp.int32), cache)
-        _, rest = jax.lax.scan(
-            step, init, self.seq_len + jnp.arange(GEN_TOKENS - 1))
-        logits = jnp.concatenate([first[:, None], rest.swapaxes(0, 1)],
-                                 axis=1)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+        with jax.named_scope("decode"):
+            _, rest = jax.lax.scan(
+                step, init, self.seq_len + jnp.arange(GEN_TOKENS - 1))
+            logits = jnp.concatenate([first[:, None], rest.swapaxes(0, 1)],
+                                     axis=1)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
 
     # -- calls -------------------------------------------------------------
     def compiled(self, batch: int, device: int = 0):
@@ -144,9 +152,11 @@ class StageRuntime:
         """Run one warmed bucket: ``tokens`` (B, seq_len) with B a bucket.
         Returns device arrays (tokens (B, G), logits (B, G, V))."""
         fn = self._compiled[(device, tokens.shape[0])]
-        return fn(self.params[device],
-                  jax.device_put(np.asarray(tokens, np.int32),
-                                 self.devices[device]))
+        with span("runtime.put"):
+            toks = jax.device_put(np.asarray(tokens, np.int32),
+                                  self.devices[device])
+        with span("runtime.launch", device=device):
+            return fn(self.params[device], toks)
 
     def profile_batch(self, batch: int) -> None:
         """One synchronous bucket-``batch`` call on device 0 (the
@@ -185,11 +195,13 @@ class StageRuntime:
             raise ValueError(f"batch of {n} exceeds the largest warmed "
                              f"bucket {self.max_batch}")
         bucket = next(b for b in self.buckets if b >= n)
-        toks = np.zeros((bucket, self.seq_len), np.int32)
-        toks[:n] = np.stack(payloads)
+        with span("runtime.pad", bucket=bucket, rows=n):
+            toks = np.zeros((bucket, self.seq_len), np.int32)
+            toks[:n] = np.stack(payloads)
         dev = self._replica_device()
         out, _ = self.generate(toks, dev)
-        out = np.asarray(out)
+        with span("runtime.fetch"):
+            out = np.asarray(out)
         with self._lock:
             self.batches[dev] += 1
         return list(out[:n])

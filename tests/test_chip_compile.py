@@ -10,6 +10,7 @@ this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -57,13 +58,20 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _named_kernel(txt: str, name: str) -> bool:
+    """A Mosaic kernel op in compiled HLO text named after its
+    ``pallas_call(name=...)``, which the profiler trace shows."""
+    return re.search(rf"%{name}(\.\d+)? = [^\n]*custom_call_target="
+                     rf'"tpu_custom_call"', txt) is not None
+
+
 def test_flash_causal_llama_widths(one_chip):
     h, kv, d = LLAMA.num_heads, LLAMA.num_kv_heads, LLAMA.resolved_head_dim
     txt = _compiled_text(
         lambda q, k, v: flash_attention(q, k, v, causal=True),
         _sds((2, 1024, h, d), one_chip), _sds((2, 1024, kv, d), one_chip),
         _sds((2, 1024, kv, d), one_chip))
-    assert "tpu_custom_call" in txt
+    assert _named_kernel(txt, "flash_attention")
 
 
 def test_decode_llama_widths_smax_2048(one_chip):
@@ -72,14 +80,14 @@ def test_decode_llama_widths_smax_2048(one_chip):
         lambda q, k, v, n: decode_attention(q, k, v, n),
         _sds((8, 1, h, d), one_chip), _sds((8, 2048, kv, d), one_chip),
         _sds((8, 2048, kv, d), one_chip), _sds((), one_chip, jnp.int32))
-    assert "tpu_custom_call" in txt
+    assert _named_kernel(txt, "decode_attention")
 
 
 @pytest.mark.parametrize("lead", [(8, 512), (3, 100)])
 def test_rmsnorm_rows(one_chip, lead):
     txt = _compiled_text(rmsnorm, _sds(lead + (LLAMA.d_model,), one_chip),
                          _sds((LLAMA.d_model,), one_chip))
-    assert "tpu_custom_call" in txt
+    assert _named_kernel(txt, "rmsnorm")
 
 
 def test_mamba_scan_jamba_widths(one_chip):

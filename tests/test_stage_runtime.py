@@ -119,3 +119,89 @@ def test_chip_smoke_fails_without_tpu():
     assert r.returncode != 0
     assert '"ok"' not in r.stdout
     assert "no TPU" in r.stderr
+
+
+def _host_spans(logdir):
+    """(name, start_ns, end_ns, thread line, args) of every executor and
+    runtime span in the trace under `logdir`."""
+    (path,) = Path(logdir).rglob("*.xplane.pb")
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for li, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(("executor.", "runtime.")):
+                    out.append((e.name, int(e.start_ns), int(e.end_ns), li,
+                                dict(e.stats)))
+    return out
+
+
+def test_traced_executor_names_every_layer(rt, tmp_path):
+    pipe = linear_pipeline("gen", [MODEL], {MODEL: ["cpu-1"]})
+    (stage,) = pipe.stages
+    prompts = _prompts(rt, 8, seed=2)
+    # tracing starts first: a replica opens its first formation span
+    # as soon as it starts
+    jax.profiler.start_trace(str(tmp_path), profiler_options=_host_spans_only())
+    ex = PipelineExecutor(pipe, PipelineConfig(
+        {stage: StageConfig("cpu-1", 4, 2)}), {MODEL: rt})
+    try:
+        lat = ex.serve_trace(np.linspace(0.0, 0.1, 8), lambda i: prompts[i],
+                             timeout_s=60.0)
+    finally:
+        jax.profiler.stop_trace()
+        ex.shutdown()
+    assert np.isfinite(lat).all()
+    spans = _host_spans(tmp_path)
+    by = {}
+    for s in spans:
+        by.setdefault(s[0], []).append(s)
+    n_batch = len(ex.batch_sizes()[stage])
+    assert sorted(s[4]["rid"] for s in by["executor.inject"]) == list(
+        range(8))
+    assert all(s[4]["lag_us"] >= 0 for s in by["executor.inject"])
+    batches = by["executor.batch"]
+    assert len(batches) == n_batch
+    for name in ("executor.complete", "runtime.pad", "runtime.put",
+                 "runtime.launch", "runtime.fetch"):
+        assert len(by[name]) == n_batch, name
+    # every batch's inner spans sit inside it, on its replica thread
+    for b in batches:
+        inner = [s for s in spans if s[3] == b[3] and b[1] <= s[1]
+                 and s[2] <= b[2] and s is not b]
+        assert [s[0] for s in sorted(inner, key=lambda s: s[1])] == [
+            "runtime.pad", "runtime.put", "runtime.launch", "runtime.fetch",
+            "executor.complete"]
+        args = {s[0]: s[4] for s in inner}
+        assert args["runtime.pad"]["rows"] == b[4]["rows"]
+        assert args["runtime.pad"]["bucket"] >= b[4]["rows"]
+        assert args["runtime.launch"] == {"device": 0}
+        assert b[4]["stage"] == stage and 0 <= b[4]["rid0"] < 8
+    # formation spans are on the replica threads, outside any batch
+    replica_lines = {b[3] for b in batches}
+    inject_lines = {s[3] for s in by["executor.inject"]}
+    assert not replica_lines & inject_lines
+    formed = [s for s in by["executor.form"] if "rows" in s[4]]
+    assert len(formed) == n_batch
+    assert {s[3] for s in formed} <= replica_lines
+    assert all(s[4]["wait_ms"] >= 0 for s in formed)
+    assert not any(b[3] == f[3] and b[1] < f[1] < b[2]
+                   for b in batches for f in formed)
+    # the batch's executor-clock start `t` maps onto the trace clock by
+    # one offset, as the harness's own spans do
+    offsets = [b[1] - b[4]["t"] * 1e9 for b in batches]
+    assert max(offsets) - min(offsets) < 50e6
+
+
+def test_program_phases_are_named_scopes(rt):
+    hlo = rt.compiled(1).as_text()
+    assert "/prefill/" in hlo and "/decode/" in hlo
+
+
+def _host_spans_only():
+    """Profiler options that record TraceMe spans and no Python calls."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return opts
