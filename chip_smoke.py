@@ -136,8 +136,9 @@ def phase_kernels() -> None:
 
     q, k, v = normal((1, 2048, h, hd)), normal((1, 2048, kv, hd)), \
         normal((1, 2048, kv, hd))
-    qd, kd, vd = normal((8, 1, h, hd)), normal((8, 2048, kv, hd)), \
-        normal((8, 2048, kv, hd))
+    # decode reads layer 1 of a head-major (L, B, KV, Smax, hd) stack
+    qd, kd, vd = normal((8, 1, h, hd)), normal((2, 8, kv, 2048, hd)), \
+        normal((2, 8, kv, 2048, hd))
     d_in, n = 16384, 16          # jamba-1.5-large mamba width, d_state
     dt = jax.nn.softplus(normal((1, 512, d_in), 0.3))
     xm, bm, cm = normal((1, 512, d_in)), normal((1, 512, n), 0.5), \
@@ -149,8 +150,8 @@ def phase_kernels() -> None:
     cases = {
         "flash_causal": (lambda: flash_attention(q, k, v, causal=True),
                          lambda: ref.flash_attention_ref(q, k, v)),
-        "decode": (lambda: decode_attention(qd, kd, vd, 1500),
-                   lambda: ref.decode_attention_ref(qd, kd, vd, 1500)),
+        "decode": (lambda: decode_attention(qd, kd, vd, 1500, 1),
+                   lambda: ref.decode_attention_ref(qd, kd, vd, 1500, 1)),
         "rmsnorm_8x128": (lambda: rmsnorm(x1, scale),
                           lambda: ref.rmsnorm_ref(x1, scale)),
         "rmsnorm_3x100": (lambda: rmsnorm(x2, scale),

@@ -8,8 +8,11 @@ processed together in one kernel instance — the group's queries form an
 memory-bound per-head matvec into a small matmul (TPU-native adaptation
 of GPU flash-decode's warp-level split-K).
 
-The cache is allocated to Smax but only ``valid_len`` slots are populated;
-valid_len arrives via scalar prefetch (SMEM) and masks the tail block.
+The cache is the model's head-major stack (L, B, KV, Smax, D), read
+where it lies: the layer index arrives via scalar prefetch (SMEM) next to
+``valid_len`` and selects the layer in the K/V index maps. The cache is
+allocated to Smax but only ``valid_len`` slots are populated; valid_len
+masks the tail block.
 Online-softmax state persists in VMEM scratch across the innermost
 (sequential) kv-block grid dimension.
 
@@ -32,7 +35,7 @@ DEFAULT_BK = 512
 NEG_INF = -1e30
 
 
-def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(vl_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                    acc_ref, m_ref, l_ref, *,
                    scale: float, window: int, bk: int, n_kv: int):
     ki = pl.program_id(2)
@@ -45,8 +48,8 @@ def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref,
 
     valid_len = vl_ref[0]
     q = q_ref[0, 0].astype(jnp.float32)          # (G, D) — the GQA group
-    k = k_ref[0, 0].astype(jnp.float32)          # (BK, D)
-    v = v_ref[0, 0].astype(jnp.float32)          # (BK, Dv)
+    k = k_ref[0, 0, 0].astype(jnp.float32)       # (BK, D)
+    v = v_ref[0, 0, 0].astype(jnp.float32)       # (BK, Dv)
     s = jnp.dot(q, k.T) * scale                  # (G, BK)
 
     k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -71,17 +74,22 @@ def _decode_kernel(vl_ref, q_ref, k_ref, v_ref, o_ref,
 
 def decode_attention(
     q: jnp.ndarray,                # (B, 1, H, D)
-    k: jnp.ndarray,                # (B, Smax, KV, D)
-    v: jnp.ndarray,                # (B, Smax, KV, Dv)
+    k: jnp.ndarray,                # (L, B, KV, Smax, D)
+    v: jnp.ndarray,                # (L, B, KV, Smax, Dv)
     valid_len,                     # scalar int — populated cache slots
+    layer=0,                       # scalar int — which of the L layers
     window: int = 0,
     scale: Optional[float] = None,
     block_k: int = DEFAULT_BK,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Returns (B, 1, H, Dv)."""
+    """Returns (B, 1, H, Dv).
+
+    ``k`` and ``v`` are a segment's whole head-major cache stacks, as
+    the model carries them; ``layer`` picks the layer inside the index
+    maps, so no slice or transpose of the cache is made."""
     b, sq, h, d = q.shape
-    _, smax, kv, dv = v.shape
+    _, _, kv, smax, dv = v.shape
     assert sq == 1, "decode kernel processes exactly one new token"
     if h % kv:
         raise ValueError(f"q heads {h} not divisible by kv heads {kv}")
@@ -94,22 +102,24 @@ def decode_attention(
 
     # (B,1,H,D) -> (B,KV,G,D): group queries per shared KV head
     qg = q[:, 0].reshape(b, kv, group, d)
-    kt = k.swapaxes(1, 2)                        # (B,KV,Smax,D)
-    vt = v.swapaxes(1, 2)
     vl = jnp.asarray(valid_len, jnp.int32).reshape(1)
+    ly = jnp.asarray(layer, jnp.int32).reshape(1)
 
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
                                bk=bk, n_kv=n_kv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, kv, n_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, group, d), lambda bb, hh, ki, vl_: (bb, hh, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda bb, hh, ki, vl_: (bb, hh, ki, 0)),
-            pl.BlockSpec((1, 1, bk, dv), lambda bb, hh, ki, vl_: (bb, hh, ki, 0)),
+            pl.BlockSpec((1, 1, group, d),
+                         lambda bb, hh, ki, vl_, ly_: (bb, hh, 0, 0)),
+            pl.BlockSpec((1, 1, 1, bk, d),
+                         lambda bb, hh, ki, vl_, ly_: (ly_[0], bb, hh, ki, 0)),
+            pl.BlockSpec((1, 1, 1, bk, dv),
+                         lambda bb, hh, ki, vl_, ly_: (ly_[0], bb, hh, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, dv),
-                               lambda bb, hh, ki, vl_: (bb, hh, 0, 0)),
+                               lambda bb, hh, ki, vl_, ly_: (bb, hh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, dv), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
@@ -122,5 +132,5 @@ def decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, kv, group, dv), q.dtype),
         name="decode_attention",
         interpret=interpret,
-    )(vl, qg, kt, vt)
+    )(vl, ly, qg, k, v)
     return out.reshape(b, 1, h, dv)

@@ -48,12 +48,11 @@ def rmsnorm(x: jnp.ndarray, scale: jnp.ndarray,
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               mask: Optional[jnp.ndarray], compute_dtype,
-              kind: Optional[str] = None, window: int = 0,
-              valid_len=None) -> jnp.ndarray:
+              kind: Optional[str] = None, window: int = 0) -> jnp.ndarray:
     """General attention entry point.
 
     `kind` describes the mask structurally so the TPU path can use the
-    flash kernels: "causal" | "full" | "decode". Off the TPU, kind None
+    flash kernel: "causal" | "full". Off the TPU, kind None
     (an explicit irregular mask) and untileable shapes take the jnp
     reference; on the TPU they raise.
     """
@@ -67,15 +66,11 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         if sq % min(128, sq) == 0 and sk % min(128, sk) == 0:
             return _pallas_flash(q, k, v, causal=(kind == "causal"),
                                  window=window, interpret=interp)
-    if (pallas or interp) and kind == "decode" and valid_len is not None:
-        if sk % min(512, sk) == 0:
-            return _pallas_decode(q, k, v, valid_len, window=window,
-                                  interpret=interp)
     if pallas:
         raise NotImplementedError(
             f"no Pallas attention kernel for kind={kind!r}, q {q.shape}, "
             f"k {k.shape} on TPU (flash needs Sq, Sk <= 128 or multiples "
-            f"of 128; decode needs Smax <= 512 or a multiple of 512)")
+            f"of 128)")
     scale = 1.0 / math.sqrt(q.shape[-1])
     if kind in ("causal", "full") and mask is None:
         # XLA path for structural masks: blockwise flash above the size
@@ -88,6 +83,23 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         if kind == "causal":
             mask = ref.causal_mask_ref(sq, sk, window, offset=sk - sq)
     return ref.attention_ref(q, k, v, mask, scale)
+
+
+def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                     valid_len, layer) -> jnp.ndarray:
+    """One token's attention over layer ``layer`` of head-major cache
+    stacks k, v (L, B, KV, Smax, hd), read in place; q (B, 1, H, hd).
+    The first ``valid_len`` slots hold keys."""
+    smax = k.shape[3]
+    if _use_pallas() or _force_interpret():
+        if smax % min(512, smax) == 0:
+            return _pallas_decode(q, k, v, valid_len, layer,
+                                  interpret=_force_interpret())
+    if _use_pallas():
+        raise NotImplementedError(
+            f"no Pallas decode kernel for a cache of {smax} slots on TPU "
+            f"(needs Smax <= 512 or a multiple of 512)")
+    return ref.decode_attention_ref(q, k, v, valid_len, layer)
 
 
 def mamba_chunk(dt, x, b, c, a, h0):

@@ -88,22 +88,32 @@ def flash_attention_ref(q, k, v, causal: bool = True, window: int = 0,
     return attention_ref(q, k, v, mask, scale)
 
 
-def decode_attention_ref(q, k, v, valid_len, window: int = 0,
+def decode_attention_ref(q, k, v, valid_len, layer=0, window: int = 0,
                          scale: Optional[float] = None):
-    """Oracle for the decode kernel.
+    """Oracle for the decode kernel, on the cache's head-major layout.
 
-    q: (B,1,H,D); k,v: (B,Smax,KV,D); valid_len: scalar or (B,) — number of
-    populated cache slots (the new token is at index valid_len-1).
+    q: (B,1,H,D); k,v: (L,B,KV,Smax,D) stacks, of which ``layer`` is
+    read; valid_len: scalar or (B,) — number of populated cache slots
+    (the new token is at index valid_len-1). Returns (B,1,H,Dv), fp32
+    softmax.
     """
-    smax = k.shape[1]
+    b, _, h, d = q.shape
+    k = jax.lax.dynamic_index_in_dim(k, layer, 0, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)
+    kv, smax = k.shape[1], k.shape[2]
+    scale = scale if scale is not None else 1.0 / math.sqrt(d)
     vl = jnp.asarray(valid_len)
     if vl.ndim == 0:
-        vl = jnp.full((q.shape[0],), vl)
+        vl = jnp.full((b,), vl)
     kj = jnp.arange(smax)[None, :]
     mask = kj < vl[:, None]
     if window > 0:
         mask &= (vl[:, None] - 1 - kj) < window
-    return attention_ref(q, k, v, mask[:, None, None, :], scale)
+    qg = q[:, 0].reshape(b, kv, h // kv, d).astype(jnp.float32)
+    s = jnp.einsum("bkgd,bksd->bkgs", qg, k.astype(jnp.float32)) * scale
+    w = jax.nn.softmax(jnp.where(mask[:, None, None], s, -jnp.inf), axis=-1)
+    out = jnp.einsum("bkgs,bksd->bkgd", w, v.astype(jnp.float32))
+    return out.reshape(b, 1, h, v.shape[-1]).astype(q.dtype)
 
 
 def mamba_scan_ref(dt, x, b, c, a, h0):

@@ -2,15 +2,20 @@
 
 The cache mirrors the model's segment structure: for each segment, a dict
 per block position whose leaves carry a leading ``repeat`` axis (so the
-decode scan can consume them alongside the stacked parameters).
+layer scan can consume them alongside the stacked parameters).
+
+Dense K/V is head-major, the layout the decode kernel reads: the layer
+scan carries the whole stack, each layer writes its rows at its own
+index, and decode attends over it where it lies (``model._run_segment``).
 
 Cache kinds per block:
-  attn  (dense KV) : k,v            (repeat, B, Smax, KV, hd)
+  attn  (dense KV) : k,v            (repeat, B, KV, Smax, hd)
   attn  (MLA)      : c_kv, k_rope   (repeat, B, Smax, kr|rope)
   mamba            : h (repeat,B,D_in,N), conv (repeat,B,dc-1,D_in)
   mlstm            : C (repeat,B,H,dh,dh), n (repeat,B,H,dh)
   slstm            : h,c,n,m        (repeat, B, D)
-  cross-attn (enc-dec): k,v over encoder states, built at prefill.
+  cross-attn (enc-dec): k,v        (repeat, B, F, KV, hd) over encoder
+                   states, built at prefill.
 """
 
 from __future__ import annotations
@@ -32,8 +37,8 @@ def _attn_cache(cfg: ArchConfig, repeat: int, batch: int, smax: int,
         }
     hd = cfg.resolved_head_dim
     return {
-        "k": jnp.zeros((repeat, batch, smax, cfg.num_kv_heads, hd), dtype),
-        "v": jnp.zeros((repeat, batch, smax, cfg.num_kv_heads, hd), dtype),
+        "k": jnp.zeros((repeat, batch, cfg.num_kv_heads, smax, hd), dtype),
+        "v": jnp.zeros((repeat, batch, cfg.num_kv_heads, smax, hd), dtype),
     }
 
 

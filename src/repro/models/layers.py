@@ -141,14 +141,17 @@ def attention(params: Params, cfg: ArchConfig, x: jnp.ndarray,
               cache: Optional[Params] = None,
               cache_pos: Optional[jnp.ndarray] = None,
               kind: Optional[str] = None,
+              layer=None,
               ) -> Tuple[jnp.ndarray, Optional[Params]]:
     """General GQA attention.
 
     * self-attention over x when kv_x is None
     * cross-attention over kv_x otherwise (no rope on cross)
-    * with `cache` (dict k,v of (B,Smax,KV,hd)) and scalar `cache_pos`:
-      single-token decode — writes the new kv at cache_pos, attends over
-      the cache prefix.
+    * with `cache` (dict k,v: the segment's head-major stacks
+      (repeat,B,KV,Smax,hd)) and scalar `layer`, this layer's K/V are
+      written into slice `layer` of the stacks: the whole prompt
+      (prefill), or with scalar `cache_pos` the one new row (single-token
+      decode, which then attends over the cache prefix in place).
     `kind` describes the mask structurally ("causal" | "full") so large
     sequences never materialize a dense mask or S^2 scores.
     Returns (output, updated_cache_or_None).
@@ -168,53 +171,54 @@ def attention(params: Params, cfg: ArchConfig, x: jnp.ndarray,
         k = apply_rope(k, positions, cfg.rope_theta)
 
     new_cache = None
-    valid_len = None
     if cache is not None:
-        smax = cache["k"].shape[1]
+        from repro.models.sharding import constrain_kv
+        smax = cache["k"].shape[3]
+        # this layer's rows, head-major: (1, B, KV, S, hd)
+        kh = k.astype(cache["k"].dtype).swapaxes(1, 2)[None]
+        vh = v.astype(cache["v"].dtype).swapaxes(1, 2)[None]
+        s = k.shape[1]
         if cache_pos is not None:
             # decode one token. Sliding-window caches (smax == window) are
             # ring buffers: slot = pos % window; RoPE is pre-applied so the
             # permuted order is harmless.
             slot = cache_pos % smax if cfg.sliding_window > 0 else cache_pos
-            from repro.models.sharding import constrain_kv
-            ck = constrain_kv(jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), slot, axis=1))
-            cv = constrain_kv(jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), slot, axis=1))
-            new_cache = {"k": ck, "v": cv}
-            k, v = ck.astype(cfg.cdtype), cv.astype(cfg.cdtype)
-            valid_len = jnp.minimum(cache_pos + 1, smax)
-            kj = jnp.arange(smax)[None, :]
-            mask = (kj < valid_len)[None, :]        # broadcast (B,H,1,smax)
-            kind = "decode"
+        elif smax >= s:
+            slot = 0
+        elif cfg.sliding_window > 0:
+            # prefill longer than the window: keep the tail, position p
+            # in ring slot p % smax
+            kh = jnp.roll(kh[:, :, :, -smax:], s % smax, axis=3)
+            vh = jnp.roll(vh[:, :, :, -smax:], s % smax, axis=3)
+            slot = 0
         else:
-            # prefill: populate the cache (tail only if window < seq)
-            s = k.shape[1]
-            kc, vc = k.astype(cache["k"].dtype), v.astype(cache["v"].dtype)
-            if smax >= s:
-                ck = jax.lax.dynamic_update_slice(
-                    cache["k"], kc, (0, 0, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cache["v"], vc, (0, 0, 0, 0))
-            else:
-                if cfg.sliding_window <= 0:
-                    raise ValueError(
-                        f"full-attention cache too small: smax={smax} < "
-                        f"prompt length {s} (did you forget the modality "
-                        f"prefix when sizing the cache?)")
-                slots = jnp.arange(s - smax, s) % smax
-                ck = cache["k"].at[:, slots].set(kc[:, -smax:])
-                cv = cache["v"].at[:, slots].set(vc[:, -smax:])
-            new_cache = {"k": ck, "v": cv}
+            raise ValueError(
+                f"full-attention cache too small: smax={smax} < "
+                f"prompt length {s} (did you forget the modality "
+                f"prefix when sizing the cache?)")
+        # one row (decode) or one layer (prefill) written into the stack
+        # where it lies: the stacks are the layer scan's carry, so XLA
+        # updates them in place.
+        start = (layer, 0, 0, slot, 0)
+        new_cache = {
+            "k": constrain_kv(jax.lax.dynamic_update_slice(
+                cache["k"], kh, start)),
+            "v": constrain_kv(jax.lax.dynamic_update_slice(
+                cache["v"], vh, start)),
+        }
+        if cache_pos is not None:
+            from repro.kernels import ops
+            out = ops.decode_attention(
+                q.astype(cfg.cdtype), new_cache["k"], new_cache["v"],
+                jnp.minimum(cache_pos + 1, smax), layer)
+            return jnp.einsum("bshk,hkd->bsd", out,
+                              params["wo"].astype(cfg.cdtype)), new_cache
 
     # GQA expansion happens inside the kernel/ref (KV heads stay compact).
-    # Decode sliding windows are enforced by the ring buffer itself (slots
-    # wrap), so the structural window only applies to prefill/train.
-    window = cfg.sliding_window if (kv_x is None and kind != "decode") else 0
+    window = cfg.sliding_window if kv_x is None else 0
     from repro.kernels import ops
     out = ops.attention(q, k, v, mask, cfg.cdtype, kind=kind,
-                        window=window,
-                        valid_len=valid_len)       # (B,Sq,H,hd)
+                        window=window)       # (B,Sq,H,hd)
     return jnp.einsum("bshk,hkd->bsd", out,
                       params["wo"].astype(cfg.cdtype)), new_cache
 

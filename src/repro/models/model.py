@@ -88,9 +88,12 @@ def _apply_block(p: Params, cfg: ArchConfig, block: Block, x: jnp.ndarray,
                  cache: Optional[Params], cache_pos,
                  enc_out: Optional[jnp.ndarray],
                  cross_cache: Optional[Params],
+                 layer=None,
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[Params],
                             Optional[Params]]:
-    """Returns (x, aux_loss, new_cache, new_cross_cache)."""
+    """Returns (x, aux_loss, new_cache, new_cross_cache). A dense-KV
+    attention block's `cache` is its segment's whole stack, written at
+    index `layer` (see ``_run_segment``)."""
     aux = jnp.zeros((), jnp.float32)
     h = L.rmsnorm(p["norm1"], cfg, x)
     new_cache = None
@@ -105,7 +108,7 @@ def _apply_block(p: Params, cfg: ArchConfig, block: Block, x: jnp.ndarray,
         else:
             out, new_cache = L.attention(p["core"], cfg, h, positions, mask,
                                          cache=cache, cache_pos=cache_pos,
-                                         kind=struct_kind)
+                                         kind=struct_kind, layer=layer)
     elif block.kind == "mamba":
         out, new_cache = L.mamba_block(p["core"], cfg, h, cache)
     elif block.kind == "mlstm":
@@ -172,9 +175,18 @@ def _run_segment(params_stack, cfg: ArchConfig, seg: Segment, x,
                  cache_stack=None, cache_pos=None,
                  enc_out=None, cross_stack=None):
     """Scan over the repeat axis. Returns (x, aux_sum, new_cache_stack,
-    new_cross_stack)."""
+    new_cross_stack).
+
+    Dense-KV caches ride in the scan's carry, whole: each layer writes
+    its rows into the stacks at its own index and the decode kernel
+    reads them there, so no layer's cache is sliced out, copied or
+    restacked. Every other cache (MLA latents, recurrent state) is
+    scanned per layer."""
     has_cache = cache_stack is not None
     has_cross = cross_stack is not None
+    # dense K/V (MHA/GQA/MQA, ring buffers included) is carried
+    dense = tuple(has_cache and b.kind == "attn" and not cfg.use_mla
+                  for b in seg.blocks)
 
     # enc-dec segments carry exactly one attention block per pattern unit
     # (whisper), so one cross K/V slot per repeat.
@@ -186,30 +198,25 @@ def _run_segment(params_stack, cfg: ArchConfig, seg: Segment, x,
         # re-pin the residual stream each layer: without this XLA may
         # resolve the FSDP weight/batch axis conflict by replicating
         # activations (see sharding.constrain_batch).
-        h = constrain_batch(carry)
-        idx = 0
-        blk_params = xs[idx]; idx += 1
-        blk_cache = (None,) * len(seg.blocks)
-        if has_cache:
-            blk_cache = xs[idx]; idx += 1
-        cross_c = xs[idx] if has_cross else None
+        h, kv = carry
+        h = constrain_batch(h)
+        layer, blk_params, scanned, cross_c = xs
         aux_sum = jnp.zeros((), jnp.float32)
-        new_caches = []
+        new_kv, new_scanned = list(kv), list(scanned)
         new_cross = cross_c
         for bi, block in enumerate(seg.blocks):
             cc = cross_c if (has_cross and block.kind == "attn") else None
+            c = kv[bi] if dense[bi] else scanned[bi]
             h, aux, nc, ncross = _apply_block(
                 blk_params[bi], cfg, block, h, positions, mask, mask_kind,
-                blk_cache[bi], cache_pos, enc_out, cc)
+                c, cache_pos, enc_out, cc, layer)
             aux_sum = aux_sum + aux
-            new_caches.append(nc if nc is not None else blk_cache[bi])
+            if dense[bi]:
+                new_kv[bi] = nc
+            elif has_cache and nc is not None:
+                new_scanned[bi] = nc
             if ncross is not None:
                 new_cross = ncross
-        outs = (aux_sum,)
-        if has_cache:
-            outs = outs + (tuple(new_caches),)
-        if has_cross:
-            outs = outs + (new_cross,)
         # Megatron-style sequence parallelism at the layer boundary: the
         # carried residual (== the activation the remat scan saves per
         # layer) is seq-sharded over `model`; XLA inserts the all-gather
@@ -224,21 +231,21 @@ def _run_segment(params_stack, cfg: ArchConfig, seg: Segment, x,
             h = constrain_batch(h, ("model",))
         else:
             h = constrain_batch(h)
-        return h, outs
+        return (h, tuple(new_kv)), (aux_sum, tuple(new_scanned), new_cross)
 
     if cfg.remat:
         body = jax.checkpoint(body)
 
-    xs = (params_stack,)
+    caches = cache_stack if has_cache else (None,) * len(seg.blocks)
+    kv = tuple(c if d else None for c, d in zip(caches, dense))
+    scanned = tuple(None if d else c for c, d in zip(caches, dense))
+    (x, kv), (aux, scanned, new_cross) = jax.lax.scan(
+        body, (x, kv), (jnp.arange(seg.repeat), params_stack, scanned,
+                        cross_stack))
+    new_cache = None
     if has_cache:
-        xs = xs + (cache_stack,)
-    if has_cross:
-        xs = xs + (cross_stack,)
-    x, ys = jax.lax.scan(body, x, xs)
-    aux = ys[0].sum()
-    new_cache = ys[1] if has_cache else None
-    new_cross = ys[2] if has_cross else None
-    return x, aux, new_cache, new_cross
+        new_cache = tuple(k if d else c for k, c, d in zip(kv, scanned, dense))
+    return x, aux.sum(), new_cache, new_cross
 
 
 # ---------------------------------------------------------------------------

@@ -146,9 +146,9 @@ def constrain_batch(x, extra=()):
 
 
 def constrain_kv(x):
-    """Pin one layer's KV-cache tensor (B, S, KV, hd) to the canonical
-    cache sharding inside the decode/prefill computation; no-op outside a
-    mesh context.
+    """Pin a segment's head-major KV-cache stack (repeat, B, KV, S, hd) to
+    the canonical cache sharding inside the decode/prefill computation;
+    no-op outside a mesh context.
 
     Mirrors ``cache_pspec``: batch over data; KV heads over `model` when
     divisible, otherwise the SEQUENCE over `model`. Without this pin SPMD
@@ -160,22 +160,22 @@ def constrain_kv(x):
     from jax.interpreters import pxla
 
     mesh = pxla.thread_resources.env.physical_mesh
-    if mesh.empty or "model" not in mesh.axis_names or x.ndim != 4:
+    if mesh.empty or "model" not in mesh.axis_names or x.ndim != 5:
         return x
     fsdp_axes, model_axis = _axes(mesh)
     fsdp_size = 1
     for a in fsdp_axes:
         fsdp_size *= mesh.shape[a]
     model_size = mesh.shape[model_axis]
-    b, s, kv, hd = x.shape
-    spec = [None, None, None, None]
+    _, b, kv, s, _ = x.shape
+    spec = [None] * 5
     if fsdp_size > 1 and b % fsdp_size == 0:
-        spec[0] = fsdp_axes if len(fsdp_axes) > 1 else fsdp_axes[0]
+        spec[1] = fsdp_axes if len(fsdp_axes) > 1 else fsdp_axes[0]
     if model_size > 1:
         if kv % model_size == 0:
             spec[2] = model_axis
         elif s % model_size == 0:
-            spec[1] = model_axis
+            spec[3] = model_axis
     return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
@@ -234,10 +234,16 @@ def batch_pspec(batch: Any, mesh: Mesh) -> Any:
 
 
 def cache_pspec(cache: Any, mesh: Mesh, shard_seq: bool = False) -> Any:
-    """Decode-cache specs: batch over data axes; KV heads / latent dim /
-    state channels over model where divisible. With ``shard_seq``
-    (long_500k, batch=1) the cache *sequence* axis shards over the data
-    axes instead — sequence-parallel attention over the long context."""
+    """Decode-cache specs for what ``init_cache`` returns, the pair
+    (decoder cache, cross K/V or None): batch over data axes; KV heads /
+    latent dim / state channels over model where divisible. With
+    ``shard_seq`` (long_500k, batch=1) the cache *sequence* axis shards
+    over the data axes instead — sequence-parallel attention over the
+    long context.
+
+    The decoder's dense K/V is head-major (rep,B,KV,S,hd); the cross
+    K/V (rep,B,F,KV,hd) and MLA latents (rep,B,S,kr) put the sequence
+    first."""
     fsdp_axes, model_axis = _axes(mesh)
     fsdp_size = 1
     for a in fsdp_axes:
@@ -253,19 +259,20 @@ def cache_pspec(cache: Any, mesh: Mesh, shard_seq: bool = False) -> Any:
         if len(shape) >= 2 and shape[1] % fsdp_size == 0 and not shard_seq:
             spec[1] = data_axes
         if re.search(r"\['(?:k|v|k_rope|c_kv)'\]$", ps) and len(shape) >= 4:
-            # dense KV (rep,B,S,KV,hd) / MLA latent (rep,B,S,kr)
-            if shard_seq and shape[2] % fsdp_size == 0:
-                spec[2] = data_axes
-            if shape[3] % model_size == 0:
-                spec[3] = model_axis
-            elif spec[2] is None and shape[2] % model_size == 0:
+            head_major = path[0].idx == 0 and re.search(r"\['[kv]'\]$", ps)
+            seq, head = (3, 2) if head_major else (2, 3)
+            if shard_seq and shape[seq] % fsdp_size == 0:
+                spec[seq] = data_axes
+            if shape[head] % model_size == 0:
+                spec[head] = model_axis
+            elif spec[seq] is None and shape[seq] % model_size == 0:
                 # GQA caches whose KV heads don't divide the model axis
                 # (qwen2 kv=8 on model=16: 1.37 TiB cache replicated
                 # model-wise). Shard the SEQUENCE dim over `model`
                 # instead — flash-decode style: each model shard holds a
                 # context slice; softmax max/sum combine via the
                 # reductions XLA already partializes.
-                spec[2] = model_axis
+                spec[seq] = model_axis
         elif re.search(r"\['(?:h|conv|C|n)'\]$", ps) and len(shape) >= 3:
             # ssm/xlstm states: channel dim over model
             ch_dim = 2 if not re.search(r"\['conv'\]$", ps) else 3

@@ -92,6 +92,12 @@ def test_flash_rejects_ragged():
 
 # ------------------------------------------------------------ decode attention
 
+def _stack(*layers):
+    """(B,Smax,KV,D) per layer -> the model's head-major cache stack
+    (L,B,KV,Smax,D)."""
+    return jnp.stack([x.swapaxes(1, 2) for x in layers])
+
+
 @pytest.mark.parametrize("b,smax,h,kv,d", [
     (1, 512, 4, 4, 64),
     (2, 1024, 8, 2, 64),
@@ -101,8 +107,8 @@ def test_flash_rejects_ragged():
 def test_decode_sweep(b, smax, h, kv, d, dtype):
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(5), 3)
     q = _rand(k1, (b, 1, h, d), dtype)
-    k = _rand(k2, (b, smax, kv, d), dtype)
-    v = _rand(k3, (b, smax, kv, d), dtype)
+    k = _stack(_rand(k2, (b, smax, kv, d), dtype))
+    v = _stack(_rand(k3, (b, smax, kv, d), dtype))
     vl = smax // 2 + 17
     out = decode_attention(q, k, v, vl, interpret=True)
     exp = ref.decode_attention_ref(q, k, v, vl)
@@ -115,8 +121,8 @@ def test_decode_sweep(b, smax, h, kv, d, dtype):
 def test_decode_valid_len_edges(vl):
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(6), 3)
     q = _rand(k1, (1, 1, 4, 64), jnp.float32)
-    k = _rand(k2, (1, 512, 2, 64), jnp.float32)
-    v = _rand(k3, (1, 512, 2, 64), jnp.float32)
+    k = _stack(_rand(k2, (1, 512, 2, 64), jnp.float32))
+    v = _stack(_rand(k3, (1, 512, 2, 64), jnp.float32))
     out = decode_attention(q, k, v, vl, interpret=True)
     exp = ref.decode_attention_ref(q, k, v, vl)
     np.testing.assert_allclose(out, exp, atol=2e-5, rtol=2e-5)
@@ -125,11 +131,33 @@ def test_decode_valid_len_edges(vl):
 def test_decode_sliding_window():
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
     q = _rand(k1, (2, 1, 4, 64), jnp.float32)
-    k = _rand(k2, (2, 512, 2, 64), jnp.float32)
-    v = _rand(k3, (2, 512, 2, 64), jnp.float32)
+    k = _stack(_rand(k2, (2, 512, 2, 64), jnp.float32))
+    v = _stack(_rand(k3, (2, 512, 2, 64), jnp.float32))
     out = decode_attention(q, k, v, 400, window=128, interpret=True)
     exp = ref.decode_attention_ref(q, k, v, 400, window=128)
     np.testing.assert_allclose(out, exp, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("vl,window", [(300, 0), (400, 128)])
+def test_decode_reads_each_layer_of_a_stack(vl, window):
+    """The kernel reads layer ``l`` of an (L,B,KV,Smax,D) stack in place:
+    for every l it equals the oracle, and the oracle equals plain masked
+    attention over that layer's keys in sequence-major order."""
+    n, b, smax, h, kv, d = 3, 2, 512, 4, 2, 64
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(9), 3)
+    q = _rand(k1, (b, 1, h, d), jnp.float32)
+    k = _rand(k2, (n, b, kv, smax, d), jnp.float32)
+    v = _rand(k3, (n, b, kv, smax, d), jnp.float32)
+    kj = jnp.arange(smax)
+    mask = (kj < vl) & ((vl - 1 - kj < window) if window else True)
+    for layer in range(n):
+        out = decode_attention(q, k, v, vl, jnp.int32(layer), window=window,
+                               interpret=True)
+        exp = ref.decode_attention_ref(q, k, v, vl, layer, window=window)
+        np.testing.assert_allclose(out, exp, atol=2e-5, rtol=2e-5)
+        plain = ref.attention_ref(q, k[layer].swapaxes(1, 2),
+                                  v[layer].swapaxes(1, 2), mask[None, None])
+        np.testing.assert_allclose(exp, plain, atol=2e-5, rtol=2e-5)
 
 
 # ------------------------------------------------------------------- rmsnorm
@@ -183,11 +211,16 @@ def test_ops_attention_raises_on_tpu_without_a_kernel(monkeypatch, kind,
     the reference (here: untiled flash and decode lengths, no kind)."""
     from repro.kernels import ops
     monkeypatch.setattr(ops, "_use_pallas", lambda: True)
-    sq = 1 if kind == "decode" else sk
-    q = jnp.zeros((1, sq, 4, 64))
+    if kind == "decode":
+        q = jnp.zeros((1, 1, 4, 64))
+        k = jnp.zeros((2, 1, 2, sk, 64))       # head-major cache stack
+        with pytest.raises(NotImplementedError, match="no Pallas decode"):
+            ops.decode_attention(q, k, k, 3, 1)
+        return
+    q = jnp.zeros((1, sk, 4, 64))
     k = jnp.zeros((1, sk, 2, 64))
     with pytest.raises(NotImplementedError, match="no Pallas attention"):
-        ops.attention(q, k, k, None, jnp.float32, kind=kind, valid_len=3)
+        ops.attention(q, k, k, None, jnp.float32, kind=kind)
 
 
 # ------------------------------------------------------- xla_flash (+ VJP)

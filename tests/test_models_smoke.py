@@ -71,27 +71,50 @@ def test_one_train_step(built, aid):
     assert max(jax.tree_util.tree_leaves(moved)) > 0
 
 
-@pytest.mark.parametrize("aid", ALL)
-def test_decode_matches_forward(built, aid):
-    """Prefill + stepwise decode reproduces full-forward logits."""
-    cfg, model, params = built[aid]
-    T = 12
-    toks = jax.random.randint(jax.random.PRNGKey(3), (1, T), 0,
+def _check_decode(cfg, model, params, b, prompt, total):
+    """Prefill `prompt` tokens, then decode one token at a time up to
+    `total`: every step's logits match the full forward's."""
+    toks = jax.random.randint(jax.random.PRNGKey(3), (b, total), 0,
                               cfg.vocab_size)
-    batch = _batch(cfg, jax.random.PRNGKey(4), b=1, t=T)
+    batch = _batch(cfg, jax.random.PRNGKey(4), b=b, t=total)
     batch["tokens"] = toks
     full, _ = model.forward(params, batch)
     pre = dict(batch)
-    pre["tokens"] = toks[:, : T - 3]
+    pre["tokens"] = toks[:, :prompt]
     npfx = cfg.num_image_tokens or 0
-    lg, cache = model.prefill(params, pre, smax=T + npfx)
-    np.testing.assert_allclose(lg[:, -1], full[:, T - 4], atol=5e-4,
+    lg, cache = model.prefill(params, pre, smax=total + npfx)
+    np.testing.assert_allclose(lg[:, -1], full[:, prompt - 1], atol=5e-4,
                                rtol=1e-3)
-    for i in range(T - 3, T):
+    for i in range(prompt, total):
         lg, cache = model.decode_step(params, toks[:, i : i + 1],
                                       jnp.int32(i + npfx), cache)
         np.testing.assert_allclose(lg[:, 0], full[:, i], atol=5e-4,
                                    rtol=1e-3)
+
+
+@pytest.mark.parametrize("aid", ALL)
+def test_decode_matches_forward(built, aid):
+    """Prefill + stepwise decode reproduces full-forward logits."""
+    cfg, model, params = built[aid]
+    _check_decode(cfg, model, params, b=1, prompt=9, total=12)
+
+
+@pytest.mark.parametrize("aid,window,prompt,total", [
+    ("phi3-mini-3.8b", None, 9, 12),            # MHA
+    ("llama3.2-1b", None, 9, 12),               # GQA
+    ("granite-34b", None, 9, 12),               # MQA
+    ("llama3.2-1b-sw", 8, 5, 14),               # ring buffer wraps in decode
+    ("llama3.2-1b-sw", 8, 11, 14),              # prompt longer than window
+    ("jamba-1.5-large-398b", None, 9, 12),      # attention + mamba segment
+])
+def test_decode_matches_forward_batched(built, aid, window, prompt, total):
+    """At batch 3 each row's cache rows stay its own: the head-major
+    stacks are written and read per row, per layer, per slot."""
+    cfg, model, params = built[aid]
+    if window is not None:
+        cfg = dataclasses.replace(cfg, sliding_window=window)
+        model = build_model(cfg)
+    _check_decode(cfg, model, params, b=3, prompt=prompt, total=total)
 
 
 @pytest.mark.parametrize("aid", ALL)

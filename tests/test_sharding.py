@@ -68,6 +68,13 @@ def test_batch_pspec_divisibility():
     batch_pspec(sds2, mesh)
 
 
+def _dense_kv_specs(specs):
+    """The decoder's dense K/V specs out of ``cache_pspec`` of
+    ``init_cache``'s (decoder cache, cross) pair."""
+    return [blk[name] for seg in specs[0] for blk in seg
+            for name in ("k", "v") if name in blk]
+
+
 def test_cache_pspec_shard_seq():
     from repro.models.kvcache import init_cache
     cfg = get_smoke("llama3.2-1b")
@@ -77,6 +84,48 @@ def test_cache_pspec_shard_seq():
     leaves = jax.tree_util.tree_leaves(
         specs, is_leaf=lambda x: isinstance(x, P))
     assert leaves  # non-empty and no exception
+    kv = _dense_kv_specs(specs)
+    assert kv
+    for spec in kv:
+        # head-major (rep, B, KV, S, hd): the sequence over data, the
+        # heads over model, the batch left whole
+        assert spec == P(None, None, "model", "data", None)
+
+
+@pytest.mark.parametrize("aid,batch,smax,expect", [
+    # MHA heads divide model: batch over data, heads over model
+    ("phi3-mini-3.8b", 4, 64, P(None, "data", "model", None, None)),
+    # GQA kv=2 on model=4: the SEQUENCE over model instead
+    ("llama3.2-1b", 4, 64, P(None, "data", None, "model", None)),
+])
+def test_cache_pspec_head_major(aid, batch, smax, expect):
+    from jax.sharding import AbstractMesh
+    from repro.models.kvcache import init_cache
+    cfg = get_smoke(aid)
+    mesh = AbstractMesh((2, 4), ("data", "model"))
+    cache = jax.eval_shape(lambda: init_cache(cfg, batch, smax))
+    kv = _dense_kv_specs(cache_pspec(cache, mesh))
+    assert kv and all(spec == expect for spec in kv)
+
+
+def test_cache_pspec_cross_and_latent_keep_sequence_major():
+    """Whisper's cross K/V (rep,B,F,KV,hd) and MLA latents (rep,B,S,kr)
+    keep the sequence before the heads / latent."""
+    from jax.sharding import AbstractMesh
+    from repro.models.kvcache import init_cache
+    mesh = AbstractMesh((2, 4), ("data", "model"))
+    whisper = get_smoke("whisper-small")
+    cache = jax.eval_shape(lambda: init_cache(whisper, 4, 64))
+    specs = cache_pspec(cache, mesh)
+    for seg in specs[1]:
+        assert seg["k"] == P(None, "data", None, "model", None)
+    assert _dense_kv_specs(specs)[0] == P(None, "data", "model", None, None)
+    ds = get_smoke("deepseek-v3-671b")
+    cache = jax.eval_shape(lambda: init_cache(ds, 4, 64))
+    latent = [blk["c_kv"] for seg in cache_pspec(cache, mesh)[0]
+              for blk in seg if "c_kv" in blk]
+    assert latent and all(sp == P(None, "data", None, "model")
+                          for sp in latent)
 
 
 _SUBPROC = textwrap.dedent("""
@@ -125,6 +174,59 @@ _SUBPROC = textwrap.dedent("""
 def test_multi_device_train_step_subprocess():
     """8 placeholder devices, (2,4) mesh, real sharded train step."""
     r = subprocess.run([sys.executable, "-c", _SUBPROC],
+                       capture_output=True, text=True, timeout=600,
+                       env={**os.environ, "PYTHONPATH": "src"},
+                       cwd=Path(__file__).resolve().parents[1])
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
+
+
+_DECODE_SUBPROC = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import jax, jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    from repro.configs import get_smoke
+    from repro.models import build_model
+    from repro.models.sharding import param_pspec
+
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
+    cfg = get_smoke("llama3.2-1b")   # kv=2 on model=4: sequence over model
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (4, 12), 0,
+                              cfg.vocab_size)
+
+    def generate(p, t):
+        lg, cache = model.prefill(p, {"tokens": t[:, :8]}, 12)
+        out = [lg[:, -1]]
+        for i in range(8, 12):
+            lg, cache = model.decode_step(p, t[:, i:i + 1], jnp.int32(i),
+                                          cache)
+            out.append(lg[:, 0])
+        return jnp.stack(out, 1), cache
+
+    want, _ = jax.jit(generate)(params, toks)
+    sp = jax.device_put(params, jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), param_pspec(params, mesh),
+        is_leaf=lambda x: isinstance(x, P)))
+    with mesh:
+        got, cache = jax.jit(generate)(sp, toks)
+    # constrain_kv pins the head-major stack (rep, B, KV, S, hd)
+    assert cache[0][0][0]["k"].sharding.spec == P(
+        None, "data", None, "model"), cache[0][0][0]["k"].sharding
+    np.testing.assert_allclose(got, want, atol=5e-4, rtol=1e-3)
+    print("OK")
+""")
+
+
+def test_multi_device_decode_subprocess():
+    """8 placeholder devices, (2,4) mesh: a sharded prefill + cached
+    decode keeps the cache stack in the canonical sharding and matches
+    the single-device logits."""
+    r = subprocess.run([sys.executable, "-c", _DECODE_SUBPROC],
                        capture_output=True, text=True, timeout=600,
                        env={**os.environ, "PYTHONPATH": "src"},
                        cwd=Path(__file__).resolve().parents[1])
