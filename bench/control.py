@@ -6,10 +6,11 @@
 For each seed, in one process: weights from the seed, a short window of
 the cell's own traffic served through the same runtime, recorder and
 executor as a benchmark run, and then, over the same sample of requests
-a run checks, the reference and the controls. A control is the plain
-reference computed a step lower (``bfloat16``, the step below the
-float32 the configuration states; ``int8``, the step below that), put
-in the program's place: its logits at the same prompts and served
+a run checks, the reference and the controls. A control is the family's
+plain reference computed a step lower (each of its ``MODES`` after the
+first; for the dense family ``bfloat16``, the step below the float32
+the configuration states, and ``int8``, the step below that), put in
+the program's place: its logits at the same prompts and served
 tokens, and at each position the token it puts first. Each goes through
 the harness's own comparison (:func:`bench.harness.judge`), so each
 line gives the numbers compared and ``correct`` for the program and
@@ -34,9 +35,6 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 
-CONTROLS = ("bfloat16", "int8")
-
-
 def readings(cell, seed: int, seconds: float, devices, registry=None):
     """The numbers compared, and ``correct``, for the program and each
     control on one seed."""
@@ -47,10 +45,10 @@ def readings(cell, seed: int, seconds: float, devices, registry=None):
     from bench.weights import base_key
     from repro.serving.runtime import GEN_TOKENS
 
-    cfg, traffic = cell.config, cell.traffic
+    cfg, traffic, family = cell.config, cell.traffic, cell.family
     key = base_key(seed)
     kw = {} if registry is None else {"registry": registry}
-    _, rt = harness.build_runtime(cfg, key, devices, **kw)
+    _, rt = harness.build_runtime(cfg, family, key, devices, **kw)
     arrival = gen.run_arrivals(traffic, seed, seconds)
     prompts = gen.rng(seed, gen.PROMPTS_STREAM).integers(
         0, int(cfg["vocab_size"]), (arrival.size, rt.seq_len), dtype=np.int32)
@@ -77,10 +75,10 @@ def readings(cell, seed: int, seconds: float, devices, registry=None):
     out = {"seed": seed, "requests": int(sample.size),
            "tokens": int(served.size)}
     with jax.default_device(devices[0]):
-        ref = reference.logits(cfg, key, seqs, GEN_TOKENS)
+        ref = family.logits(cfg, key, seqs, GEN_TOKENS)
         reads = {"program": (served, logits)}
-        for mode in CONTROLS:
-            lg = reference.logits(cfg, key, seqs, GEN_TOKENS, mode=mode)
+        for mode in family.MODES[1:]:
+            lg = family.logits(cfg, key, seqs, GEN_TOKENS, mode=mode)
             reads[mode] = (lg.argmax(-1), lg)
     for name, (tokens, lg) in reads.items():
         checks = harness.judge(cfg, ref, tokens, lg, 0)

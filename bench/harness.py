@@ -1,23 +1,22 @@
 """Run one cell: set up, serve the window, check the answers, report.
 
 1. Build the configuration the cell's data fixes: the registry's model
-   at the file's depth, weights from the seed, a ``StageRuntime`` with
-   every bucket compiled and run once, and a one-stage pipeline whose
-   batch cap, replicas and batch timeout come from the file. Nothing is
-   profiled or planned here.
+   cut as the file says (by its family, ``bench/families/``), weights
+   from the seed, a ``StageRuntime`` with every bucket compiled and run
+   once, and a one-stage pipeline whose batch cap, replicas and batch
+   timeout come from the file. Nothing is profiled or planned here.
 2. Serve the mix open loop through ``PipelineExecutor.serve_trace``: a
    warm-up segment (``warmup_s``), then the measured window of
    ``seconds``. The window holds the requests whose nominal arrival
    falls inside it; latency runs from that due time.
-3. With the program's state freed, run the plain reference over a
-   sample of the window's requests, drawn from the seed before serving,
-   and compare it with their served tokens and with the logits the timed
-   path computed for them (:func:`judge`).
+3. With the program's state freed, run the family's plain reference
+   over a sample of the window's requests, drawn from the seed before
+   serving, and compare it with their served tokens and with the logits
+   the timed path computed for them (:func:`judge`).
 """
 
 from __future__ import annotations
 
-import dataclasses
 import gc
 import json
 import shutil
@@ -29,7 +28,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import numpy as np
 
-from bench import arrivals as gen, flops, reference, stats, trace as tr
+from bench import arrivals as gen, reference, stats, trace as tr
 from bench.record import Call, Run
 from bench.serve import StageRecorder, seeded_runtime, warm
 from bench.spec import BENCH_DIR, Cell, metric_reader
@@ -38,7 +37,6 @@ from repro.configs import get_arch
 from repro.core.hardware import hardware_for_device
 from repro.core.pipeline import PipelineConfig, StageConfig, linear_pipeline
 from repro.models import build_model
-from repro.models.config import dense_segments
 from repro.serving.executor import PipelineExecutor
 from repro.serving.runtime import GEN_TOKENS
 
@@ -68,34 +66,6 @@ def load_peak(kind: str) -> Dict[str, Any]:
     return peaks[kind]
 
 
-def build_arch(config: Dict[str, Any], registry: Callable = get_arch):
-    """The registry's model at the file's depth and RMSNorm epsilon;
-    every other size in the file has to be what the registry serves."""
-    arch = dataclasses.replace(
-        registry(config["registry_id"]),
-        segments=dense_segments(int(config["num_hidden_layers"])),
-        norm_eps=float(config["rms_norm_eps"]))
-    want = {"d_model": config["hidden_size"],
-            "num_heads": config["num_attention_heads"],
-            "num_kv_heads": config["num_key_value_heads"],
-            "d_ff": config["intermediate_size"],
-            "vocab_size": config["vocab_size"],
-            "act": "swiglu" if config["hidden_act"] == "silu" else "gelu",
-            "rope_theta": config["rope_theta"],
-            "param_dtype": config["param_dtype"],
-            "compute_dtype": config["compute_dtype"],
-            "tie_embeddings": config["tie_word_embeddings"]}
-    got = {k: getattr(arch, k) for k in want}
-    if got != want:
-        bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
-        raise ValueError(f"registry {config['registry_id']!r} differs from "
-                         f"the configuration file (registry, file): {bad}")
-    if arch.family != "dense" or arch.qkv_bias or arch.sliding_window:
-        raise ValueError("the reference covers dense models without "
-                         "biases or windows only")
-    return arch
-
-
 class Compiles:
     """JAX monitoring listener: XLA compiles while it is registered."""
 
@@ -123,11 +93,12 @@ class GcPauses:
             self.seconds.append(time.perf_counter() - self._t0)
 
 
-def build_runtime(config: Dict[str, Any], key, devices: List,
+def build_runtime(config: Dict[str, Any], family, key, devices: List,
                   registry: Callable = get_arch):
-    """(arch, runtime): weights from `key`, every bucket compiled and
-    run once on every device."""
-    arch = build_arch(config, registry)
+    """(arch, runtime): the model as its `family` builds it from
+    `config`, weights from `key`, every bucket compiled and run once on
+    every device."""
+    arch = family.build_arch(config, registry)
     shapes = jax.eval_shape(build_model(arch).init, jax.random.PRNGKey(0))
     srv = config["serving"]
     rt = seeded_runtime(arch, devices, int(srv["prompt_tokens"]),
@@ -209,7 +180,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         raise ValueError(f"the runtime generates {GEN_TOKENS} tokens, the "
                          f"file says {srv['gen_tokens']}")
     key = base_key(seed)
-    arch, rt = build_runtime(cfg, key, devices, registry)
+    arch, rt = build_runtime(cfg, cell.family, key, devices, registry)
 
     arrival = gen.run_arrivals(traffic, seed, seconds)
     n = arrival.size
@@ -264,7 +235,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     gc.unfreeze()
     gc.collect()
 
-    run = Run(config=cfg, dims=flops.dims(cfg),
+    run = Run(config=cfg, family=cell.family,
               peak=load_peak(devices[0].device_kind)
               if devices[0].platform == "tpu" else {},
               prompt=prompt, gen=GEN_TOKENS, buckets=tuple(
@@ -312,7 +283,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                       ) if sample.size else np.zeros((0, GEN_TOKENS), np.int32)
     t0 = time.perf_counter()
     with jax.default_device(devices[0]):
-        ref = reference.logits(cfg, key, reference.teacher_forced(
+        ref = cell.family.logits(cfg, key, reference.teacher_forced(
             prompts[sample], served), GEN_TOKENS) if sample.size else None
     log(f"[check] reference over {sample.size} requests "
         f"({served.size} served tokens) took {time.perf_counter() - t0:.1f}s")

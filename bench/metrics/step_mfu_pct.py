@@ -1,9 +1,7 @@
 """The step's share of the chip's bf16 peak: operations that the
-window's real rows need (padding rows excluded,
-:func:`bench.flops.request_flops`) over the device time of their
-programs times the peak."""
-
-from bench import flops
+window's real rows need (padding rows excluded; the family's
+``request_flops``) over the device time of their programs times the
+peak."""
 
 
 def read(run):
@@ -13,6 +11,6 @@ def read(run):
     busy = sum(dev for _, _, dev in calls)
     if busy <= 0.0:
         return None
-    work = sum(c.rows for c, _, _ in calls) * flops.request_flops(
-        run.dims, run.prompt, run.gen)
+    work = sum(c.rows for c, _, _ in calls) * run.family.request_flops(
+        run.config, run.prompt, run.gen)
     return 100.0 * work / (busy * run.peak["bf16_flops_per_s"])

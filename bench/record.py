@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+from types import ModuleType
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -30,7 +31,7 @@ class Call:
 @dataclasses.dataclass
 class Run:
     config: Dict[str, Any]
-    dims: flops.Dims
+    family: Optional[ModuleType]    # bench/families/<config["family"]>.py
     peak: Dict[str, Any]
     prompt: int
     gen: int
@@ -134,7 +135,8 @@ def step_ms(run: Run) -> Optional[float]:
 
 def kernel_roofline_pct(run: Run, kernel: str) -> Optional[float]:
     """The least time the chip could take for `kernel`'s calls in the
-    window's stage calls, over the time they took."""
+    window's stage calls (the family's count of each call), over the
+    time they took."""
     if not run.traced():
         return None
     ideal = took = 0.0
@@ -143,8 +145,8 @@ def kernel_roofline_pct(run: Run, kernel: str) -> Optional[float]:
         if t <= 0.0:
             continue
         took += t
-        for name, f, nbytes in flops.generate_kernel_calls(
-                run.dims, run.bucket(c.rows), run.prompt, run.gen):
+        for name, f, nbytes in run.family.kernel_calls(
+                run.config, run.bucket(c.rows), run.prompt, run.gen):
             if name == kernel:
                 ideal += flops.roofline_s(f, nbytes, run.peak)
     return 100.0 * ideal / took if took > 0.0 else None

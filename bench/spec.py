@@ -1,8 +1,10 @@
-"""Find a cell's configuration, traffic mix and metrics by name.
+"""Find a cell's configuration, model family, traffic mix and metrics by
+name.
 
-Nothing here knows a cell, configuration, mix or metric by name: each is
-a file under ``bench/`` that ``BENCHMARK.json`` points to, so a later
-change adds a file and an entry and edits nothing that is there.
+Nothing here knows a cell, configuration, mix, model family or metric
+by name: each is a file under ``bench/`` that ``BENCHMARK.json`` or a
+configuration file points to, so a later change adds a file and an entry
+and edits nothing that is there.
 """
 
 from __future__ import annotations
@@ -11,12 +13,17 @@ import dataclasses
 import importlib.util
 import json
 import re
+import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+# what a family file (bench/families/<family>.py) gives the harness
+FAMILY_API = ("build_arch", "logits", "MODES", "request_flops",
+              "kernel_calls")
 
 
 def _check_name(name: str) -> str:
@@ -40,6 +47,7 @@ class Cell:
     traffic_name: str
     chips: int
     config: Dict[str, Any]
+    family: ModuleType          # bench/families/<config["family"]>.py
     traffic: Dict[str, Any]
     end_to_end: List[Metric]
     per_layer: List[Metric]
@@ -70,7 +78,12 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
         raise KeyError(f"unknown workload {workload!r}; have {sorted(cells)}")
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = load_json(root / configs[w["config"]]["file"])
+    path = root / configs[w["config"]]["file"]
+    config = load_json(path)
+    if "family" not in config:
+        raise KeyError(f"{path}: no 'family' key; a configuration file "
+                       f"names its model family, a file under "
+                       f"bench/families/")
     traffic = load_traffic(_check_name(w["traffic"]), root)
     e2e = [m for m in map(_metric, bench["end_to_end"])
            if reports(m, workload, [])]
@@ -78,7 +91,8 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in map(_metric, bench["per_layer"])
                  if reports(m, workload, names)]
     return Cell(workload, w["config"], w["traffic"], int(w["chips"]),
-                config, traffic, e2e, per_layer)
+                config, family(config["family"], root), traffic, e2e,
+                per_layer)
 
 
 def load_json(path: Path) -> Dict[str, Any]:
@@ -95,6 +109,7 @@ def _load_module(path: Path, modname: str):
         raise FileNotFoundError(path)
     spec = importlib.util.spec_from_file_location(modname, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[modname] = mod      # dataclasses look their module up there
     spec.loader.exec_module(mod)
     return mod
 
@@ -103,6 +118,30 @@ def traffic_kind(kind: str, root: Path = ROOT) -> Callable:
     """The ``segments(params, t0, t1)`` function of one generator kind."""
     path = root / "bench" / "traffic" / "kinds" / f"{_check_name(kind)}.py"
     return _load_module(path, f"bench_traffic_kind_{kind}").segments
+
+
+def family(name: str, root: Path = ROOT) -> ModuleType:
+    """The module of one model family: everything that depends on the
+    model's shape.
+
+    * ``build_arch(config, registry)``: the registry's model cut as the
+      configuration file says, checked against the file;
+    * ``logits(config, key, seqs, last, mode="float32", rows=64)``: the
+      plain reference's logits at the last `last` positions, computed in
+      blocks of `rows` sequences from the seed's weights, importing
+      nothing of the program; ``MODES[0]`` is ``"float32"``, the
+      reference, and the rest are its controls, the nearest first;
+    * ``request_flops(config, prompt, gen)``: operations one request
+      needs;
+    * ``kernel_calls(config, bucket, prompt, gen)``: ``(kernel, flops,
+      bytes)`` of every kernel call of one generate.
+    """
+    path = root / "bench" / "families" / f"{_check_name(name)}.py"
+    mod = _load_module(path, "bench_family_" + re.sub(r"[.\-]", "_", name))
+    missing = [a for a in FAMILY_API if not hasattr(mod, a)]
+    if missing:
+        raise ValueError(f"{path} does not define {missing}")
+    return mod
 
 
 def metric_reader(name: str, root: Path = ROOT) -> Callable:

@@ -70,7 +70,8 @@ def main() -> int:
     bench = spec.load_benchmark()
     entry = next(c for c in bench["configs"] if c["name"] == args.config)
     cfg = spec.load_json(spec.ROOT / entry["file"])
-    _, rt = harness.build_runtime(cfg, base_key(args.seed), devices)
+    _, rt = harness.build_runtime(cfg, spec.family(cfg["family"]),
+                                  base_key(args.seed), devices)
     print(json.dumps({"setup_s": time.perf_counter() - T_START,
                       "memory_peak_bytes": max(
                           (d.memory_stats() or {}).get("peak_bytes_in_use", 0)

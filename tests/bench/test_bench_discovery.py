@@ -5,14 +5,21 @@ import os
 import shutil
 import subprocess
 import sys
+import time
 from pathlib import Path
 
+import jax
+import numpy as np
 import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
+sys.path.insert(0, str(Path(__file__).resolve().parent))
 
-from bench import spec  # noqa: E402
+from bench import harness, spec, trace as tr  # noqa: E402
+from bench.record import Call, Run  # noqa: E402
+from repro.configs import get_smoke  # noqa: E402
+from smallcell import small_cell  # noqa: E402
 
 BENCH = spec.load_benchmark()
 
@@ -25,6 +32,9 @@ def test_every_cell_resolves(cell):
     assert any(m.name == "setup_s" for m in c.end_to_end)
     assert len(c.end_to_end) >= 2 and c.per_layer
     assert spec.traffic_kind(c.traffic["kind"])
+    fam = spec.family(c.config["family"])
+    assert c.family.__file__ == fam.__file__
+    assert fam.MODES[0] == "float32" and len(fam.MODES) >= 2
     for m in c.per_layer:
         assert callable(spec.metric_reader(m.name))
 
@@ -55,7 +65,7 @@ def _fake_root(tmp_path: Path) -> Path:
     (root / "bench" / "traffic" / "mix-new.json").write_text(json.dumps(
         {"kind": "burst2", "r": 3.0, "warmup_s": 1.0}))
     (root / "bench" / "configs" / "model-new.json").write_text(json.dumps(
-        {"name": "model-new"}))
+        {"name": "model-new", "family": "dense"}))
     (root / "bench" / "metrics" / "new_metric.x.py").write_text(
         "def read(run):\n    return 42.0\n")
     bench = json.loads(json.dumps(BENCH))
@@ -75,7 +85,7 @@ def _fake_root(tmp_path: Path) -> Path:
 def test_new_files_are_found_by_name(tmp_path):
     root = _fake_root(tmp_path)
     cell = spec.load_cell("new-cell", root)
-    assert cell.config == {"name": "model-new"}
+    assert cell.config == {"name": "model-new", "family": "dense"}
     assert [m.name for m in cell.end_to_end] == ["setup_s"]
     assert [m.name for m in cell.per_layer] == ["new_metric.x"]
     assert spec.metric_reader("new_metric.x", root)(None) == 42.0
@@ -90,6 +100,98 @@ def test_unknown_names_are_errors(tmp_path):
         spec.metric_reader("no_such_metric")
     with pytest.raises(ValueError):
         spec.traffic_kind("../escape")
+
+
+PROBE = '''"""The dense family, with every call recorded."""
+from bench.families import dense
+
+CALLS = []
+MODES = dense.MODES
+
+
+def _recorded(name):
+    def call(*args, **kwargs):
+        CALLS.append(name)
+        return getattr(dense, name)(*args, **kwargs)
+    return call
+
+
+build_arch = _recorded("build_arch")
+logits = _recorded("logits")
+request_flops = _recorded("request_flops")
+kernel_calls = _recorded("kernel_calls")
+'''
+
+
+def _add_config(root: Path, name: str, **keys) -> None:
+    """A configuration file `name` (phi3-mini-3.8b's, with `keys` set or,
+    where None, left out) and a cell `<name>-cell` serving it."""
+    cfg = spec.load_json(ROOT / "bench" / "configs" / "phi3-mini-3.8b.json")
+    cfg.update(keys, name=name)
+    cfg = {k: v for k, v in cfg.items() if v is not None}
+    (root / "bench" / "configs" / f"{name}.json").write_text(json.dumps(cfg))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": name, "source": "x",
+                             "file": f"bench/configs/{name}.json",
+                             "reduced": [], "why": "x"})
+    bench["workloads"].append({"name": f"{name}-cell", "config": name,
+                               "traffic": "phi3-overload", "chips": 1,
+                               "why": "x"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+def test_a_family_is_found_by_name(tmp_path):
+    """A family added as one file, named by a configuration added as
+    another, serves a whole run: every call that depends on the model's
+    shape goes to it."""
+    root = _fake_root(tmp_path)
+    (root / "bench" / "families" / "probe.py").write_text(PROBE)
+    _add_config(root, "probe-model", family="probe")
+    cell = spec.load_cell("probe-model-cell", root)
+    assert Path(cell.family.__file__) == root / "bench" / "families" / \
+        "probe.py"
+    small = small_cell("probe-model", "phi3-overload", 20.0, root)
+    probe = small.family
+    res = harness.run_cell(small, 2**31 + 7, 2.0, False, jax.devices()[:1],
+                           time.perf_counter(), registry=get_smoke,
+                           log=lambda s: None)
+    assert res["correct"], res["checks"]
+    assert probe.CALLS == ["build_arch", "logits"]
+    # the operation counts of a traced run: one stage call of 2 rows
+    ms = 1_000_000
+    trace = tr.Trace({0: [("flash_attention", 2 * ms, 3 * ms),
+                          ("decode_attention", 3 * ms, 4 * ms)]},
+                     {0: [("jit_generate", 2 * ms, 4 * ms)]},
+                     {0: (ms, 5 * ms)})
+    run = Run(config=small.config, family=probe,
+              peak=harness.load_peak("TPU v5 lite"), prompt=128, gen=8,
+              buckets=(1, 2), arrival=np.zeros(2), started=np.zeros(2),
+              done=np.zeros(2), w0=0.0, w1=0.01,
+              calls=[Call(0, 0, 0.0, 0.004, 2)], trace=trace)
+    run.offset_ns = tr.clock_offset_ns(trace, {0: 0.0})
+    for name in ("step_mfu_pct", "flash_attention_roofline",
+                 "decode_attention_roofline"):
+        assert spec.metric_reader(name)(run) > 0.0
+    assert probe.CALLS[2:] == ["request_flops", "kernel_calls",
+                               "kernel_calls"]
+
+
+def test_unknown_or_missing_family_is_an_error(tmp_path):
+    root = _fake_root(tmp_path)
+    (root / "bench" / "families" / "half.py").write_text(
+        "MODES = ('float32',)\n\ndef logits(*a):\n    pass\n")
+    _add_config(root, "no-family", family=None)
+    _add_config(root, "unknown-family", family="no_such_family")
+    _add_config(root, "bad-family", family="../dense")
+    _add_config(root, "half-family", family="half")
+    with pytest.raises(KeyError, match="'family'"):
+        spec.load_cell("no-family-cell", root)
+    with pytest.raises(FileNotFoundError, match="no_such_family"):
+        spec.load_cell("unknown-family-cell", root)
+    with pytest.raises(ValueError, match="bad name"):
+        spec.load_cell("bad-family-cell", root)
+    with pytest.raises(ValueError, match="build_arch"):
+        spec.load_cell("half-family-cell", root)
 
 
 def _run(args, cwd, env_extra=None):

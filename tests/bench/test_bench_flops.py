@@ -8,36 +8,40 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 from bench import flops  # noqa: E402
+from bench.families import dense  # noqa: E402
 from bench.spec import BENCH_DIR, load_json  # noqa: E402
 
 
-def _dims(name):
-    return flops.dims(load_json(BENCH_DIR / "configs" / f"{name}.json"))
+def _config(name):
+    return load_json(BENCH_DIR / "configs" / f"{name}.json")
 
 
 def test_phi3_request_flops_by_hand():
-    m = _dims("phi3-mini-3.8b")
+    cfg = _config("phi3-mini-3.8b")
+    m = dense.dims(cfg)
     # per layer: q, k, v, o 4 x 3072^2 = 37,748,736; gate/up/down
     # 3 x 3072 x 8192 = 75,497,472
-    assert flops.layer_matmul_params(m) == 113_246_208
-    dense = 2 * 113_246_208 * 16 * (128 + 7)
+    assert dense.layer_matmul_params(m) == 113_246_208
+    matmuls = 2 * 113_246_208 * 16 * (128 + 7)
     head = 2 * 3072 * 32064 * 8
     pairs = 128 * 129 // 2 + sum(range(129, 136))   # 8256 + 924
     attn = 4 * 32 * 96 * pairs * 16
-    assert flops.request_flops(m, 128, 8) == pytest.approx(dense + head + attn)
+    assert dense.request_flops(cfg, 128, 8) == pytest.approx(
+        matmuls + head + attn)
     # about 3.65 GFLOP for each of the 135 tokens a request runs
-    assert 3.6e9 < flops.request_flops(m, 128, 8) / 135 < 3.7e9
+    assert 3.6e9 < dense.request_flops(cfg, 128, 8) / 135 < 3.7e9
 
 
 def test_kernel_calls_by_hand():
-    m = _dims("phi3-mini-3.8b")
-    f, b = flops.flash_call(m, 16, 128)
+    cfg = _config("phi3-mini-3.8b")
+    m = dense.dims(cfg)
+    f, b = dense.flash_call(m, 16, 128)
     assert f == 4 * 16 * 32 * 96 * 128 * 129 / 2
     assert b == 4 * 16 * 128 * 96 * (2 * 32 + 2 * 32)     # q, o, k, v in f32
-    f, b = flops.decode_call(m, 16, 130)
+    f, b = dense.decode_call(m, 16, 130)
     assert f == 4 * 16 * 32 * 96 * 130
     assert b == 4 * 16 * 96 * (2 * 32 + 2 * 32 * 130)
-    calls = list(flops.generate_kernel_calls(m, 16, 128, 8))
+    calls = list(dense.kernel_calls(cfg, 16, 128, 8))
     assert sum(c[0] == "flash_attention" for c in calls) == 16
     assert sum(c[0] == "decode_attention" for c in calls) == 16 * 7
 

@@ -59,7 +59,7 @@ def _run(n_calls=2):
     spans.sort(key=lambda s: (s.start, -s.end))
     trace = tr.Trace({0: [(n, a, b) for n, a, b in ops]}, {0: modules},
                      {k: (t, t + 22 * MS) for k, t in enumerate(starts)})
-    run = Run(config={}, dims=None, peak={}, prompt=128, gen=8,
+    run = Run(config={}, family=None, peak={}, prompt=128, gen=8,
               buckets=(1, 2), arrival=np.array([0.0, 0.001, 0.002, 0.003,
                                                 0.2]),
               started=np.full(5, 0.01), done=np.full(5, 0.05), w0=0.0,

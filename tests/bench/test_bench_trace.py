@@ -1,6 +1,7 @@
 """Trace reduction: busy union, idle share, time by operation and
 kernel, device time per stage call, and naming of idle gaps."""
 
+import json
 import sys
 from pathlib import Path
 
@@ -10,6 +11,7 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
 from bench import flops, harness, trace as tr  # noqa: E402
+from bench.families import dense  # noqa: E402
 from bench.record import Call, Run, host_ms, kernel_roofline_pct, step_ms  # noqa: E402
 from bench.spec import BENCH_DIR, load_json, metric_reader  # noqa: E402
 
@@ -45,7 +47,7 @@ def _synthetic_run():
         ops.append(("fusion", t + 9 * MS + MS // 2, t + 17 * MS))
     trace = tr.Trace({0: ops}, {0: modules}, spans)
     offset = 1_000 * MS                                # trace = host + 1 s
-    run = Run(config=cfg, dims=flops.dims(cfg),
+    run = Run(config=cfg, family=dense,
               peak=harness.load_peak("TPU v5 lite"), prompt=128, gen=8,
               buckets=(1, 2, 4), arrival=np.array([0.0, 0.0005, 0.02]),
               started=np.array([0.001, 0.001, 0.04]),
@@ -68,20 +70,85 @@ def test_per_call_arithmetic():
     c = run.calls[0]
     assert run.kernel_s(c, "flash_attention") == pytest.approx(1e-3)
     assert run.kernel_s(c, "decode_attention") == pytest.approx(3.5e-3)
-    m, peak = run.dims, run.peak
-    want = flops.roofline_s(*flops.flash_call(m, 2, 128), peak) \
-        + flops.roofline_s(*flops.flash_call(m, 1, 128), peak)
+    m, peak = dense.dims(run.config), run.peak
+    want = flops.roofline_s(*dense.flash_call(m, 2, 128), peak) \
+        + flops.roofline_s(*dense.flash_call(m, 1, 128), peak)
     assert kernel_roofline_pct(run, "flash_attention") == pytest.approx(
         100 * want / 2e-3)
     assert metric_reader("step_ms.overload")(run) == pytest.approx(15.0)
     assert metric_reader("runtime_host_ms.overload")(run) == pytest.approx(
         (14.0 + 5.0) / 2)
     mfu = metric_reader("step_mfu_pct")(run)
-    assert mfu == pytest.approx(100 * 3 * flops.request_flops(m, 128, 8)
-                                / (30e-3 * peak["bf16_flops_per_s"]))
+    assert mfu == pytest.approx(100 * 3 * dense.request_flops(
+        run.config, 128, 8) / (30e-3 * peak["bf16_flops_per_s"]))
     # busy: 2 x 15 ms of the 70 ms window
     assert metric_reader("device_idle_pct")(run) == pytest.approx(
         100 * (1 - 30 / 70))
+    # what each reader gave before the counts moved into the family
+    assert _readings(run) == pytest.approx(SYNTHETIC_READINGS, rel=1e-12)
+
+
+READERS = ("step_ms.overload", "runtime_host_ms.overload", "step_mfu_pct",
+           "flash_attention_roofline", "decode_attention_roofline",
+           "device_idle_pct")
+SYNTHETIC_READINGS = {
+    "step_ms.overload": 15.000000000000002,
+    "runtime_host_ms.overload": 9.5,
+    "step_mfu_pct": 1.637831965888325,
+    "flash_attention_roofline": 1.1522813186813186,
+    "decode_attention_roofline": 1.1972923076923074,
+    "device_idle_pct": 57.14285714285714}
+# the readers over the recorded phi3 trace below, as the harness read
+# them when its operation counts lived in bench/flops.py
+RECORDED_READINGS = {
+    "step_ms.overload": 14.524617000000003,
+    "runtime_host_ms.overload": 2.5848469999999995,
+    "step_mfu_pct": 3.300255804992527,
+    "flash_attention_roofline": 40.56043221096549,
+    "decode_attention_roofline": 25.89467975198017,
+    "device_idle_pct": 97.1988561}
+
+
+def _readings(run):
+    return {name: metric_reader(name)(run) for name in READERS}
+
+
+def test_readers_over_four_devices():
+    """One replica per device, as on a four-chip host: every device's
+    calls count, and the idle share is the devices' mean."""
+    cfg = dict(load_json(BENCH_DIR / "configs" / "phi3-mini-3.8b.json"),
+               num_hidden_layers=1)
+    ops, modules, spans, calls = {}, {}, {}, []
+    for dev in range(4):
+        ops[dev], modules[dev] = [], []
+        for j in range(2):
+            k = 2 * dev + j
+            t = (10 + 30 * j) * MS + dev * MS
+            prog = (2 + dev) * MS                  # device d: 2 + d ms
+            spans[k] = (t, t + prog + 3 * MS)      # 3 ms of host a call
+            modules[dev].append(("jit_generate", t + MS, t + MS + prog))
+            ops[dev].append(("fusion", t + MS, t + MS + prog))
+            calls.append(Call(k, dev, t * 1e-9, (t + prog + 3 * MS) * 1e-9,
+                              1 + dev))
+    run = Run(config=cfg, family=dense,
+              peak=harness.load_peak("TPU v5 lite"),
+              prompt=128, gen=8, buckets=(1, 2, 4), arrival=np.zeros(1),
+              started=np.zeros(1), done=np.zeros(1), w0=0.0, w1=0.08,
+              calls=calls, trace=tr.Trace(ops, modules, spans))
+    run.offset_ns = tr.clock_offset_ns(run.trace,
+                                       {c.index: c.t0 for c in calls})
+    assert run.devices() == [0, 1, 2, 3]
+    assert metric_reader("runtime_host_ms.overload")(run) == pytest.approx(
+        3.0)
+    assert metric_reader("step_ms.overload")(run) == pytest.approx(
+        (2 + 3 + 4 + 5) / 4)
+    # device d is busy 2 x (2 + d) ms of the 80 ms window
+    assert metric_reader("device_idle_pct")(run) == pytest.approx(
+        100 * (1 - sum(2 * (2 + d) for d in range(4)) / 4 / 80))
+    busy = sum(2 * (2 + d) * 1e-3 for d in range(4))
+    assert metric_reader("step_mfu_pct")(run) == pytest.approx(
+        100 * 2 * (1 + 2 + 3 + 4) * dense.request_flops(cfg, 128, 8)
+        / (busy * run.peak["bf16_flops_per_s"]))
 
 
 def test_breakdown_names_gaps():
@@ -145,3 +212,20 @@ def test_recorded_chip_trace():
     busy = tr.busy_ns(tr.merge(t.ops[0]), progs[0][0], progs[1][1])
     inside = sum(e - s for s, e in progs.values())
     assert 0.9 * inside < busy <= inside
+    # every reader over two stage calls of phi3-mini-3.8b at 2 layers
+    # (batch 1 and 2), recorded on a TPU v5e, gives what it gave before
+    path = str(DATA / "phi3_two_layers_spans.xplane.pb")
+    with open(DATA / "phi3_two_layers_spans.calls.json") as f:
+        recorded = {int(k): v for k, v in json.load(f).items()}
+    cfg = dict(load_json(BENCH_DIR / "configs" / "phi3-mini-3.8b.json"),
+               num_hidden_layers=2)
+    trace = tr.load(path)
+    run = Run(config=cfg, family=dense,
+              peak=harness.load_peak("TPU v5 lite"),
+              prompt=128, gen=8, buckets=(1, 2), arrival=np.zeros(3),
+              started=np.zeros(3), done=np.zeros(3), w0=0.0, w1=1.0,
+              calls=[Call(k, c["device"], c["t0"], c["t1"], c["rows"])
+                     for k, c in sorted(recorded.items())], trace=trace)
+    run.offset_ns = tr.clock_offset_ns(
+        trace, {k: c["t0"] for k, c in recorded.items()})
+    assert _readings(run) == pytest.approx(RECORDED_READINGS, rel=1e-12)
