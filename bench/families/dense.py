@@ -9,6 +9,8 @@ shape (``bench.spec.family`` finds it by the configuration file's
 
 * :func:`build_arch`: the registry's model cut as the file says, checked
   against the file;
+* :func:`init_rule`: how each leaf's seeded weights are drawn
+  (:mod:`bench.weights`);
 * :func:`logits` and :data:`MODES`: the plain reference and its
   controls;
 * :func:`request_flops` and :func:`kernel_calls`: operations and bytes
@@ -93,6 +95,26 @@ def build_arch(config: Dict[str, Any], registry: Callable):
     return arch
 
 
+# the input-side matrices, each drawn with fan-in its first axis
+MATRICES = ("wq", "wk", "wv", "wg", "wu", "wd")
+
+
+def init_rule(name: str, shape: Tuple[int, ...]) -> Tuple:
+    """How leaf `name` (one layer's `shape`) is drawn: the embedding and
+    the head small, norm gains near 1, every matrix by its fan-in (the
+    attention output's over its heads and head size)."""
+    last = name.rsplit("/", 1)[-1]
+    if last in ("embed", "unembed"):
+        return ("embed",)
+    if last == "scale":
+        return ("scale",)
+    if last == "wo":
+        return ("fan_in", (0, 1))
+    if last in MATRICES:
+        return ("fan_in", (0,))
+    raise ValueError(f"the dense family draws no leaf {name!r} {shape}")
+
+
 # -- the plain reference ------------------------------------------------------
 
 LAYER = "segments/0/0/"
@@ -137,7 +159,7 @@ def _layer_shapes(a: Arch) -> Dict[str, tuple]:
 
 @functools.partial(jax.jit, static_argnums=(1,))
 def _layer_weights(key, a: Arch, layer):
-    return {n: leaf(key, LAYER + n, layer, shape)
+    return {n: leaf(key, LAYER + n, layer, shape, init_rule)
             for n, shape in _layer_shapes(a).items()}
 
 
@@ -238,14 +260,16 @@ def _block(w, x, a: Arch, mode: str):
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
 def _embed(key, tokens, a: Arch, mode: str):
-    table = _weight(leaf(key, "embed", 0, (a.vocab, a.d)), "embed", mode)
+    table = _weight(leaf(key, "embed", 0, (a.vocab, a.d), init_rule),
+                    "embed", mode)
     return table[tokens]
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))
 def _head(key, x, a: Arch, mode: str):
-    scale = leaf(key, "final_norm/scale", 0, (a.d,))
-    w = _weight(leaf(key, "unembed", 0, (a.d, a.vocab)), "unembed", mode)
+    scale = leaf(key, "final_norm/scale", 0, (a.d,), init_rule)
+    w = _weight(leaf(key, "unembed", 0, (a.d, a.vocab), init_rule),
+                "unembed", mode)
     h = _act(_rms(x, scale, a.eps), mode)
     return jnp.einsum("bsd,dv->bsv", h, w, precision=_prec(mode, a),
                       preferred_element_type=jnp.float32)

@@ -22,6 +22,7 @@ import json
 import shutil
 import sys
 import tempfile
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -41,6 +42,9 @@ from repro.serving.executor import PipelineExecutor
 from repro.serving.runtime import GEN_TOKENS
 
 TOP = 10        # entries of each breakdown list
+# the traced stretch: the window, and this much on either side, so that
+# every call the window holds is whole in the trace
+TRACE_MARGIN_S = 1.0
 
 
 class NoChip(RuntimeError):
@@ -96,14 +100,14 @@ class GcPauses:
 def build_runtime(config: Dict[str, Any], family, key, devices: List,
                   registry: Callable = get_arch):
     """(arch, runtime): the model as its `family` builds it from
-    `config`, weights from `key`, every bucket compiled and run once on
-    every device."""
+    `config`, weights from `key` drawn by the family's ``init_rule``,
+    every bucket compiled and run once on every device."""
     arch = family.build_arch(config, registry)
     shapes = jax.eval_shape(build_model(arch).init, jax.random.PRNGKey(0))
     srv = config["serving"]
     rt = seeded_runtime(arch, devices, int(srv["prompt_tokens"]),
                         int(srv["max_batch"]),
-                        lambda: make_params(key, shapes))
+                        lambda: make_params(key, shapes, family.init_rule))
     warm(rt)
     return arch, rt
 
@@ -133,6 +137,57 @@ def profile_options():
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1     # the harness's own spans, little else
     return opts
+
+
+class WindowTrace:
+    """A profiler trace of ``[t0, t1)`` on an executor's clock, started
+    and stopped from a thread of its own while the executor serves, so
+    the warm-up and the drain are left out of it."""
+
+    def __init__(self, logdir: str, clock: Callable[[], float], t0: float,
+                 t1: float, log=print):
+        self.logdir, self.clock, self.t0, self.t1 = logdir, clock, t0, t1
+        self.log = log
+        self.error: Optional[BaseException] = None
+        self._done = threading.Event()
+        self._thread = threading.Thread(target=self._run, daemon=True)
+
+    def begin(self) -> None:
+        """Start waiting for ``t0``; call once the clock is zeroed."""
+        self._thread.start()
+
+    def _wait_until(self, t: float) -> bool:
+        """Whether the clock reached `t` before :meth:`finish` was asked."""
+        while not self._done.is_set():
+            left = t - self.clock()
+            if left <= 0:
+                return True
+            self._done.wait(min(left, 0.05))
+        return False
+
+    def _run(self) -> None:
+        try:
+            if not self._wait_until(self.t0):
+                return
+            t = time.perf_counter()
+            jax.profiler.start_trace(self.logdir,
+                                     profiler_options=profile_options())
+            started = time.perf_counter() - t
+            self._wait_until(self.t1)
+            t, end = time.perf_counter(), self.clock()
+            jax.profiler.stop_trace()
+            self.log(f"[trace] {self.t0:.1f}-{end:.1f} s traced; start "
+                     f"{started:.2f} s, stop {time.perf_counter() - t:.1f} s")
+        except BaseException as e:      # re-raised by finish()
+            self.error = e
+
+    def finish(self) -> None:
+        """Stop the trace now if ``t1`` has not come, and wait for it."""
+        self._done.set()
+        if self._thread.ident is not None:
+            self._thread.join()
+        if self.error is not None:
+            raise self.error
 
 
 def breakdown(run: Run) -> Dict[str, List]:
@@ -188,7 +243,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         0, vocab, (n, prompt), dtype=np.int32)
     plist = list(prompts)
     w0 = float(traffic["warmup_s"])
-    sample = check_sample(cfg, seed, arrival, w0, w0 + seconds)
+    w1 = w0 + seconds
+    sample = check_sample(cfg, seed, arrival, w0, w1)
     rec = StageRecorder(fault(rt) if fault else rt, rt,
                         {id(p): i for i, p in enumerate(plist)}, keep=sample)
     ex = make_executor(cell.name, cfg, rt, devices, rec)
@@ -206,9 +262,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         f"{rt.buckets}, {replicas} replica(s), {n} requests, "
         f"setup {setup_s:.1f}s")
 
-    logdir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
+    window = None
     if trace:
-        jax.profiler.start_trace(logdir, profiler_options=profile_options())
+        window = WindowTrace(tempfile.mkdtemp(prefix="bench_trace_"), ex.now,
+                             w0 - TRACE_MARGIN_S, w1 + TRACE_MARGIN_S, log)
+        start_run = ex.start_run
+
+        def start_run_and_trace():
+            start_run()             # zeroes the clock the trace follows
+            window.begin()
+        ex.start_run = start_run_and_trace
     pauses = GcPauses()
     gc.callbacks.append(pauses)
     compiles = Compiles()
@@ -219,9 +282,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     finally:
         jax.monitoring.unregister_event_duration_listener(compiles)
         gc.callbacks.remove(pauses)
-        if trace:
-            jax.profiler.stop_trace()
-        ex.shutdown()
+        try:
+            if window:
+                window.finish()
+        finally:
+            ex.shutdown()
     memory_peak = max(int(d.memory_stats().get("peak_bytes_in_use", 0))
                       if d.memory_stats() else 0 for d in devices)
     injection = ex.injection_stats() or {}
@@ -242,7 +307,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                   1 << i for i in range((int(srv["max_batch"]) - 1)
                                         .bit_length() + 1)),
               arrival=arrival, started=started, done=arrival + lat,
-              w0=w0, w1=w0 + seconds, calls=calls)
+              w0=w0, w1=w1, calls=calls)
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind,
               "count": len(jax.devices()),
@@ -250,8 +315,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     out: Dict[str, Any] = {}
     if trace:
         t0 = time.perf_counter()
-        run.trace = tr.load(tr.find_xplane(logdir))
-        shutil.rmtree(logdir, ignore_errors=True)
+        run.trace = tr.load(tr.find_xplane(window.logdir))
+        shutil.rmtree(window.logdir, ignore_errors=True)
         log(f"[trace] {sum(map(len, run.trace.ops.values()))} operations, "
             f"{len(run.trace.spans)} stage calls, read in "
             f"{time.perf_counter() - t0:.1f}s")
@@ -270,7 +335,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             device["window_s"] = (wn1 - wn0) * 1e-9
             out["breakdown"] = breakdown(run)
     else:
-        e2e = stats.end_to_end(arrival, arrival + lat, w0, w0 + seconds)
+        e2e = stats.end_to_end(arrival, arrival + lat, w0, w1)
         e2e["setup_s"] = setup_s
         metrics = {m.name: {"value": e2e[m.name], "unit": m.unit}
                    for m in cell.end_to_end}
@@ -288,7 +353,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     log(f"[check] reference over {sample.size} requests "
         f"({served.size} served tokens) took {time.perf_counter() - t0:.1f}s")
     checks = judge(cfg, ref, served, logits, failed)
-    lat_due = stats.latency_ms(arrival, arrival + lat, w0, w0 + seconds)
+    lat_due = stats.latency_ms(arrival, arrival + lat, w0, w1)
     longest = max(calls, key=lambda c: c.t1 - c.t0)
     log(f"[serve] due in window {len(due)}, answered {len(due) - failed}, "
         f"attainment {stats.attainment_pct(lat_due, traffic['slo_ms']):.2f}% "
@@ -296,7 +361,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         f"{stats.percentile(lat_due, 50):.2f} ms, p95 "
         f"{stats.percentile(lat_due, 95):.2f} ms, p99 "
         f"{stats.percentile(lat_due, 99):.2f} ms, throughput "
-        f"{stats.throughput_rps(arrival + lat, w0, w0 + seconds):.3f} req/s, "
+        f"{stats.throughput_rps(arrival + lat, w0, w1):.3f} req/s, "
         f"batches {len(calls)}, mean rows "
         f"{np.mean([c.rows for c in calls]) if calls else 0:.2f}, "
         f"injection lag p99 {injection.get('p99_lag_s', 0) * 1e3:.2f} ms "

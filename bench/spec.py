@@ -22,8 +22,8 @@ BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 # what a family file (bench/families/<family>.py) gives the harness
-FAMILY_API = ("build_arch", "logits", "MODES", "request_flops",
-              "kernel_calls")
+FAMILY_API = ("build_arch", "init_rule", "logits", "MODES",
+              "request_flops", "kernel_calls")
 
 
 def _check_name(name: str) -> str:
@@ -126,6 +126,9 @@ def family(name: str, root: Path = ROOT) -> ModuleType:
 
     * ``build_arch(config, registry)``: the registry's model cut as the
       configuration file says, checked against the file;
+    * ``init_rule(name, shape)``: how the seeded weights draw the leaf
+      `name` of one layer's `shape` (:mod:`bench.weights`); raises for
+      a leaf the family does not know;
     * ``logits(config, key, seqs, last, mode="float32", rows=64)``: the
       plain reference's logits at the last `last` positions, computed in
       blocks of `rows` sequences from the seed's weights, importing

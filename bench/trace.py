@@ -10,7 +10,7 @@ on those intervals:
   share is 1 minus busy over the window;
 * time per operation kind and per kernel, by name;
 * device time of each stage call: the one program it ran, paired with
-  it on its device (:func:`assign_programs`);
+  it in order on its device (:func:`assign_programs`);
 * idle gaps, each named by what the host was doing in its middle.
 
 Operations are named by kind: the HLO instruction name without its
@@ -66,11 +66,18 @@ def load(path: str) -> Trace:
         if m:
             dev = int(m.group(1))
             names = meta.get(plane.name, {})
+            kinds: Dict[str, str] = {}      # an operation's name -> kind
+
+            def kind(name: str) -> str:
+                if name not in kinds:
+                    kinds[name] = op_name(name, names.get(name, {}))
+                return kinds[name]
+
             for line in plane.lines:
                 if line.name == "XLA Ops":
                     ops[dev] = leaves(
-                        (op_name(e.name, names.get(e.name, {})),
-                         int(e.start_ns), int(e.end_ns)) for e in line.events)
+                        (kind(e.name), int(e.start_ns), int(e.end_ns))
+                        for e in line.events)
                 elif line.name == "XLA Modules":
                     modules[dev] = sorted(
                         ((e.name, int(e.start_ns), int(e.end_ns))
@@ -155,25 +162,31 @@ def time_by_kind(intervals: Iterable[Interval], w0: int, w1: int
 def assign_programs(trace: Trace, calls: Iterable[Tuple[int, int]]
                     ) -> Dict[int, Tuple[int, int]]:
     """The device program of each stage call, from ``(call index,
-    device)`` pairs. A call runs one program, so where a device holds as
-    many programs as traced calls they pair in order; otherwise each
-    call takes the programs that start inside its host span."""
+    device)`` pairs.
+
+    A call runs one program, and one device runs its calls' programs in
+    the order of the calls. Before its first traced call a device may
+    hold programs of no traced call (a replica's warm-up, or a call
+    already running when the trace began), and after its last one a
+    call the trace stopped inside. So each device's traced calls, in the
+    order their spans start, pair in order with its programs from the
+    one that starts nearest the first call's span. The device's clock
+    and the host's differ by up to a millisecond, so a program is never
+    paired by lying inside a span: the next call's may start there.
+    """
     by_dev: Dict[int, List[int]] = {}
     for k, dev in calls:
         if k in trace.spans:
             by_dev.setdefault(dev, []).append(k)
     out: Dict[int, Tuple[int, int]] = {}
     for dev, ks in by_dev.items():
-        ks.sort(key=lambda k: trace.spans[k][0])
         progs = trace.modules.get(dev, [])
-        if len(progs) == len(ks):
-            out.update({k: (s, e) for k, (_, s, e) in zip(ks, progs)})
+        if not progs:
             continue
-        for k in ks:
-            a, b = trace.spans[k]
-            inside = [(s, e) for _, s, e in progs if a <= s <= b]
-            if len(inside) == 1:
-                out[k] = inside[0]
+        ks.sort(key=lambda k: trace.spans[k][0])
+        first = trace.spans[ks[0]][0]
+        j = min(range(len(progs)), key=lambda i: abs(progs[i][1] - first))
+        out.update({k: (s, e) for k, (_, s, e) in zip(ks, progs[j:])})
     return out
 
 

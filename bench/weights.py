@@ -7,22 +7,29 @@ anything from the other.
 
 Leaf names are ``/``-joined paths in the program's parameter tree
 (``segments/0/0/core/wq``); leaves under ``segments`` carry a leading
-layer axis. Scales follow the usual fan-in rule, so activations keep
-unit size through the depth:
+layer axis. How each leaf is drawn is its family's to say: the family
+file's ``init_rule(name, shape)`` (``bench/families/<family>.py``)
+takes a leaf's name and one layer's shape and returns one of
 
-* ``embed``, ``unembed``: N(0, 0.02^2)
-* norm ``scale``: 1 + N(0, 0.1^2)
-* ``wo`` (heads, head_dim, d): N(0, 1 / (heads * head_dim))
-* any other matrix: N(0, 1 / fan_in), fan_in its first axis.
+* ``("embed",)``: N(0, 0.02^2);
+* ``("scale",)``: 1 + N(0, 0.1^2), a norm's gain;
+* ``("fan_in", axes)``: N(0, 1 / n), n the product of ``shape[a]``
+  over ``axes``, so activations keep unit size through the depth,
+
+and raises for a leaf it does not know. This file draws; it reads no
+leaf's name.
 """
 
 from __future__ import annotations
 
 import math
 import zlib
+from typing import Callable, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+
+Rule = Callable[[str, Tuple[int, ...]], Tuple]
 
 
 def base_key(seed: int) -> jax.Array:
@@ -33,22 +40,25 @@ def base_key(seed: int) -> jax.Array:
     return jax.random.fold_in(jax.random.key(seed & 0x7FFFFFFF), seed >> 31)
 
 
-def leaf(key: jax.Array, name: str, layer, shape, dtype=jnp.float32
-         ) -> jax.Array:
-    """One layer's value of leaf `name` (`layer` may be traced)."""
+def _draw(z: jax.Array, how: Sequence, shape) -> jax.Array:
+    """Standard normal `z` scaled as the rule `how` says."""
+    if how[0] == "embed":
+        return 0.02 * z
+    if how[0] == "scale":
+        return 1.0 + 0.1 * z
+    if how[0] == "fan_in":
+        return z / math.sqrt(math.prod(shape[a] for a in how[1]))
+    raise ValueError(f"unknown rule {how!r}")
+
+
+def leaf(key: jax.Array, name: str, layer, shape, rule: Rule,
+         dtype=jnp.float32) -> jax.Array:
+    """One layer's value of leaf `name` (`layer` may be traced), drawn
+    as ``rule(name, shape)`` says."""
     k = jax.random.fold_in(jax.random.fold_in(key, zlib.crc32(name.encode())),
                            layer)
     z = jax.random.normal(k, shape, jnp.float32)
-    last = name.rsplit("/", 1)[-1]
-    if last in ("embed", "unembed"):
-        v = 0.02 * z
-    elif last == "scale":
-        v = 1.0 + 0.1 * z
-    elif last == "wo":
-        v = z / math.sqrt(shape[0] * shape[1])
-    else:
-        v = z / math.sqrt(shape[0])
-    return v.astype(dtype)
+    return _draw(z, rule(name, tuple(shape)), shape).astype(dtype)
 
 
 def _path_name(path) -> str:
@@ -58,19 +68,20 @@ def _path_name(path) -> str:
     return "/".join(parts)
 
 
-def make_params(key: jax.Array, shapes):
+def make_params(key: jax.Array, shapes, rule: Rule):
     """The whole parameter tree for `shapes` (a pytree of
     ``ShapeDtypeStruct``, as ``jax.eval_shape(model.init, ...)`` gives),
-    in one jitted call on the default device."""
+    each leaf drawn by `rule`, in one jitted call on the default
+    device."""
 
     def build(key):
         def one(path, s):
             name = _path_name(path)
             if name.startswith("segments/"):
                 return jax.vmap(lambda l: leaf(key, name, l, s.shape[1:],
-                                               s.dtype))(
+                                               rule, s.dtype))(
                     jnp.arange(s.shape[0]))
-            return leaf(key, name, 0, s.shape, s.dtype)
+            return leaf(key, name, 0, s.shape, rule, s.dtype)
         return jax.tree_util.tree_map_with_path(one, shapes)
 
     return jax.jit(build)(key)

@@ -35,6 +35,7 @@ def test_every_cell_resolves(cell):
     fam = spec.family(c.config["family"])
     assert c.family.__file__ == fam.__file__
     assert fam.MODES[0] == "float32" and len(fam.MODES) >= 2
+    assert callable(fam.init_rule)
     for m in c.per_layer:
         assert callable(spec.metric_reader(m.name))
 
@@ -117,6 +118,7 @@ def _recorded(name):
 
 
 build_arch = _recorded("build_arch")
+init_rule = _recorded("init_rule")
 logits = _recorded("logits")
 request_flops = _recorded("request_flops")
 kernel_calls = _recorded("kernel_calls")
@@ -156,7 +158,11 @@ def test_a_family_is_found_by_name(tmp_path):
                            time.perf_counter(), registry=get_smoke,
                            log=lambda s: None)
     assert res["correct"], res["checks"]
-    assert probe.CALLS == ["build_arch", "logits"]
+    # the served weights are drawn by the probe's rule, leaf by leaf
+    rules = probe.CALLS.count("init_rule")
+    assert rules >= 10
+    assert probe.CALLS == ["build_arch"] + ["init_rule"] * rules + ["logits"]
+    del probe.CALLS[1:1 + rules]
     # the operation counts of a traced run: one stage call of 2 rows
     ms = 1_000_000
     trace = tr.Trace({0: [("flash_attention", 2 * ms, 3 * ms),

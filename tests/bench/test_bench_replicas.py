@@ -1,6 +1,7 @@
 """The four-chip cell's run on four CPU devices, at a small size, with
 the timed path sound and then broken underneath the harness: each
-replica serves from its own device, and ``correct`` has to follow.
+replica serves from its own device, and ``correct`` has to follow. The
+family's controls, read over the same four replicas, are not correct.
 
 The run needs four devices, which the CPU backend gives only to a
 process that asks before JAX starts, so the test runs this file as a
@@ -25,7 +26,7 @@ SEED = 2**31 + 11
 def main() -> None:
     sys.path[:0] = [str(ROOT), str(ROOT / "src"), str(HERE)]
     import jax
-    from bench import harness
+    from bench import control, harness
     from repro.configs import get_smoke
     from smallcell import small_cell
     from test_bench_faults import FAILS, altered_token, half_batch
@@ -56,6 +57,8 @@ def main() -> None:
                        if c["value"] > c["limit"]]}
     out["devices_used"] = sorted(used)
     out["fails"] = FAILS
+    out["controls"] = control.readings(cell, SEED + 1, 2.0, devices,
+                                       registry=get_smoke)
     print(json.dumps(out))
 
 
@@ -79,6 +82,14 @@ def test_four_replicas_sound_run_is_correct(runs):
 def test_four_replicas_broken_timed_path_is_not_correct(runs, fault):
     assert not runs[fault]["correct"]
     assert runs["fails"][fault] in runs[fault]["failed"]
+
+
+@pytest.mark.parametrize("mode", ["bfloat16", "int8"])
+def test_four_replicas_control_is_not_correct(runs, mode):
+    r = runs["controls"]
+    assert r["program.correct"], r
+    assert r["requests"] >= 16
+    assert not r[f"{mode}.correct"]
 
 
 if __name__ == "__main__":
